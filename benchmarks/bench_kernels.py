@@ -45,6 +45,7 @@ except ModuleNotFoundError:  # `python benchmarks/bench_kernels.py`
     from benchmarks.common import fmt_table, time_fn
 from repro.core.registry import REGISTRY, ExecutionPolicy
 from repro.kernels import ops
+from repro.launch.compile_cache import enable_compile_cache
 from repro.kernels.fused import quantize_weight
 from repro.models.attention import quantize_kv
 
@@ -551,6 +552,7 @@ def main() -> None:
                     help="max allowed new/old median ratio (same-meta "
                     "runs only)")
     args = ap.parse_args()
+    enable_compile_cache()
     result = run(quick=args.quick, out=args.out)
     if args.compare:
         with open(args.compare) as f:

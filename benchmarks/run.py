@@ -12,9 +12,11 @@ import os
 import sys
 
 from benchmarks import dialect_audit, roofline_table, tablev
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     which = sys.argv[1] if len(sys.argv) > 1 else "all"
     results = {}
     if which in ("all", "dialect"):

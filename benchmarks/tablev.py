@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from benchmarks.common import fmt_table, time_fn
 from repro.kernels import ops
+from repro.launch.compile_cache import enable_compile_cache
 from repro.kernels.attention import structural_cost as attn_cost
 from repro.kernels.gemm import structural_cost as gemm_cost
 from repro.kernels.histogram import structural_cost as hist_cost
@@ -130,4 +131,5 @@ def run() -> dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

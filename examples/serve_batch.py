@@ -47,7 +47,8 @@ def main():
     dt = time.perf_counter() - t0
     tokens = sum(len(r.generated) for r in done)
     print(f"[serve_batch] {len(done)} requests -> {tokens} tokens "
-          f"in {dt:.2f}s ({tokens / dt:.1f} tok/s, CPU)")
+          f"in {dt:.2f}s ({tokens / dt:.1f} tok/s on "
+          f"{jax.devices()[0].device_kind}, compile included)")
     for r in done:
         print(f"  rid={r.rid}: {r.generated}")
     assert len(done) == args.requests

@@ -166,7 +166,7 @@ def main():
     mesh = make_production_mesh(multi_pod=multi)
     cell = cells_lib.build_cell(arch, shape, mesh,
                                 par_overrides=overrides or None)
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = cell.lower().compile()
     mod = HloModule(compiled.as_text(), chips)
     entry = mod.entry or next(iter(mod.comps))

@@ -30,11 +30,28 @@ from repro.core.execution_model import choose_block_bytes
 #: minimal second-minor granule of a TPU f32 tile (sublanes)
 SUBLANES = 8
 
-#: jax renamed TPUCompilerParams -> CompilerParams across releases; the
-#: plan is the single place kernels get compiler params from, so the
-#: version shim lives here.
-CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
+#: the scoped-VMEM limit the TPU compiler gives a kernel that sets none
+#: (16 MiB on v5e); tiles planned against the dialect's larger VMEM
+#: budget must raise it, or the compiler refuses them
+DEFAULT_SCOPED_VMEM = 16 * 1024 * 1024
+
+
+def compiler_params(semantics: Optional[Tuple[str, ...]] = None,
+                    vmem_bytes: int = 0):
+    """The ``compiler_params`` of one ``pallas_call``.
+
+    ``semantics`` is the native budget's ``dimension_semantics`` (None
+    for the abstract budgets, which claim no pipeline annotation).
+    ``vmem_bytes`` is the plan's resident working set: past the
+    compiler's default scoped limit the limit is raised to the target's
+    VMEM budget, the size every plan was tiled against.  Kernels always
+    compile for the target chip, whatever dialect chose their tiles."""
+    kw = {}
+    if semantics is not None:
+        kw["dimension_semantics"] = semantics
+    if vmem_bytes > DEFAULT_SCOPED_VMEM:
+        kw["vmem_limit_bytes"] = TARGET.scratchpad_bytes
+    return pltpu.CompilerParams(**kw) if kw else None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -55,7 +72,7 @@ class PipelinePlan:
         """Pipeline annotations are a native feature: abstract budgets get
         none (the compiler still runs, but the kernel claims nothing)."""
         if self.mode == "native":
-            return CompilerParams(dimension_semantics=self.semantics)
+            return compiler_params(self.semantics)
         return None
 
     @property

@@ -39,6 +39,8 @@ import inspect
 import warnings
 from typing import Callable, Dict, Mapping, Optional, Tuple
 
+import jax
+
 from repro.core.dialect import Dialect, TARGET, get_dialect
 from repro.core.primitives import (ContractViolation, IsaMode,
                                    KernelContract, validate_contract)
@@ -160,18 +162,12 @@ def use_mesh_axes(axes: Mapping[str, int]):
 
 def ambient_mesh_axes() -> Dict[str, int]:
     """The ambient mesh axis sizes: :func:`use_mesh_axes` first, else the
-    active ``jax.Mesh`` context (``with mesh:``), else empty."""
+    mesh entered with ``jax.set_mesh`` (also visible while tracing under
+    it), else empty."""
     axes = _mesh_axes_var.get()
     if axes is not None:
         return dict(axes)
-    try:  # resolve from an active `with Mesh(...)` context, if any
-        from jax.interpreters import pxla
-        mesh = pxla.thread_resources.env.physical_mesh
-        if mesh is not None and not mesh.empty:
-            return dict(mesh.shape)
-    except Exception:
-        pass
-    return {}
+    return dict(jax.sharding.get_abstract_mesh().shape)
 
 
 def tp_axis_size(axis: str = TP_AXIS) -> int:

@@ -38,7 +38,7 @@ from repro.core import (IsaMode, KernelContract, Primitive, REGISTRY,
                         row_reduce_shuffle, scratch_tree_bytes,
                         scratch_tree_reduce, tree_stages,
                         tuned_attention_blocks, validate_contract)
-from repro.core.pipeline import CompilerParams
+from repro.core.pipeline import compiler_params
 from repro.kernels import ref as _ref
 
 NEG_INF = -1e30  # finite sentinel: keeps exp() NaN-free on fully-masked rows
@@ -114,7 +114,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
                   red_ref, *, scale: float, causal: bool, kv_offset: int,
                   block_q: int, block_kv: int, n_kv: int, mode: str,
                   skip: bool, kv_len: int | None = None, q_axis: int = 2,
-                  kv_axis: int = 3, epilogue=None, pos_ref=None,
+                  kv_axis: int = 3, epilogue=None, pos=None,
                   skip_dead: bool = False, k_scale_ref=None,
                   v_scale_ref=None):
     """One online-softmax block program.
@@ -128,10 +128,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     heads are sequential).  ``epilogue`` is the hook the fused lowerings
     plug into: called with the finalized ``acc / l`` block *in VMEM*
     instead of the plain ``o_ref`` store — the attention output then
-    never exists in HBM (kernels/fused.py).  ``pos_ref`` is the
-    decode-shaped mask source: a per-sequence (1, 1) int32 block holding
-    the number of valid cache entries minus one — keys at columns
-    ``> pos`` are masked, replacing the static causal triangle with the
+    never exists in HBM (kernels/fused.py).  ``pos`` is the
+    decode-shaped mask source: the sequence's int32 scalar (read from an
+    SMEM prefetch operand) holding the number of valid cache entries
+    minus one — keys at columns ``> pos`` are masked, replacing the static causal triangle with the
     traced per-slot cache frontier (the serve tick's batch mixes
     positions, so the mask cannot be a static kv_offset).
 
@@ -159,10 +159,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # (bq, bkv)
-        if pos_ref is not None:
+        if pos is not None:
             cols = ki * block_kv + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 1)
-            s = jnp.where(cols <= pos_ref[0, 0], s, NEG_INF)
+            s = jnp.where(cols <= pos, s, NEG_INF)
         elif causal:
             rows = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 0) + kv_offset
@@ -186,12 +186,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
             preferred_element_type=jnp.float32)
         m_ref[...] = m_cur
 
-    if skip_dead and pos_ref is not None:
+    if skip_dead and pos is not None:
         # Paged decode: grid-level predication on the *traced* per-slot
         # frontier — table entries whose first logical column lies past
         # ``pos`` are dead (reserved-but-unreached or sentinel) and are
         # skipped entirely, so the kv walk only visits live pages.
-        @pl.when(ki * block_kv <= pos_ref[0, 0])
+        @pl.when(ki * block_kv <= pos)
         def _():
             body()
     elif causal and skip:
@@ -252,10 +252,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     grid = (b, h, sqp // block_q, skvp // block_kv)
     skip = (mode == "native")
 
-    params = None
-    if mode == "native":
-        params = CompilerParams(dimension_semantics=(
-            "parallel", "parallel", "parallel", "arbitrary"))
+    params = compiler_params(
+        ("parallel", "parallel", "parallel", "arbitrary")
+        if mode == "native" else None)
 
     out = pl.pallas_call(
         functools.partial(

@@ -66,7 +66,7 @@ from repro.core import (IsaMode, KernelContract, Primitive, REGISTRY,
                         TARGET, align_up, register_op_space,
                         scratch_tree_bytes, tree_stages, tuned_plan,
                         validate_contract)
-from repro.core.pipeline import CompilerParams
+from repro.core.pipeline import compiler_params
 from repro.core.tuning import (attention_matmul_bucket, swiglu_bucket,
                                tuned_entry)
 from repro.kernels import attention as _attention
@@ -149,6 +149,49 @@ def _q8_weight_stream(rereads: int, k: int, n: int) -> int:
     """int8 weight elements + the f32 per-channel scale row, re-fetched
     once per row-block sweep like the f32 weight tile they replace."""
     return (k * n * 1 + n * 4) * rereads
+
+
+def _padded_d(d: int, mode: str) -> int:
+    """The feature width a norm-prologue kernel stages: the portable
+    budgets pad it to the lanes, native takes it as is."""
+    return d if mode == "native" else align_up(d, LANES)
+
+
+def _fit_tile(tile: int, dim: int) -> int:
+    """The largest lane multiple ``<= tile`` that divides ``dim`` rounded
+    up to the lanes: a dimension is never padded past its lane alignment
+    (a 1152 tile would pad 6144 columns to 6912)."""
+    units = align_up(dim, LANES) // LANES
+    t = max(1, min(tile, units * LANES) // LANES)
+    while units % t:
+        t -= 1
+    return t * LANES
+
+
+def row_kernel_tiles(bm: int, bn: int, rows: int, d: int, n: int,
+                     itemsize: int, n_weights: int = 1):
+    """``(bm, bn, vmem_bytes)`` for the norm-prologue kernels.
+
+    Their feature row stays whole (the moment needs it), so a step holds
+    a ``(bm, d)`` row block, ``n_weights`` ``(d, bn)`` weight tiles and a
+    ``(bm, bn)`` output block.  The resolved tile is fitted to the problem
+    (:func:`_fit_tile`), then halved along its longer edge until one
+    stage keeps the Eq. 1 double-buffered occupancy of 2 on the chip's
+    VMEM — the same invariant the tuner enforces, and what leaves room
+    for the kernel's f32 temporaries.  ``vmem_bytes`` is the resident
+    pipeline (two buffers per operand) the compiler must admit."""
+    bm, bn = _fit_tile(bm, rows), _fit_tile(bn, n)
+
+    def stage(bm, bn):
+        return (bm * d + d + n_weights * d * bn + bm * bn) * itemsize
+
+    while (TARGET.buffer_occupancy(stage(bm, bn), 2) < 2
+           and max(bm, bn) > LANES):
+        if bn >= bm:
+            bn = _fit_tile(bn // 2, n)
+        else:
+            bm = _fit_tile(bm // 2, rows)
+    return bm, bn, 2 * stage(bm, bn)
 
 # --------------------------------------------------------------------------
 # Contracts: the fused ops spend the union of their constituents' budgets.
@@ -278,16 +321,18 @@ def _rmsnorm_matmul_kernel(*refs, eps: float, mode: str, d_true: int,
     # one shared source for the per-mode moment discipline (rmsnorm.py)
     y = _rmsnorm.normalize_block(x, w, scratch_ref, eps=eps, mode=mode,
                                  d_true=d_true)
-    p = p_ref[...].astype(jnp.float32)                    # (d, bn)
+    p = p_ref[...]                                        # (d, bn)
     if s_ref is not None:
         # the quantized prologue: the weight block arrives int8 and its
         # (1, bn) per-channel scales rescale it HERE, in VMEM — the f32
         # weight never exists in HBM (ISSUE 7).
-        p = p * s_ref[...]
+        p = p.astype(jnp.float32) * s_ref[...]
     # the epilogue: the normalized block goes straight into the MXU
-    # contraction from VMEM — it never exists in HBM.
+    # contraction from VMEM — it never exists in HBM.  Both operands
+    # enter at the weight's width (the unfused pair's einsum does the
+    # same), so a bf16 weight tile is never widened to an f32 copy.
     o_ref[...] = jax.lax.dot_general(
-        y, p, (((1,), (0,)), ((), ())),
+        y.astype(p.dtype), p, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
@@ -336,8 +381,8 @@ def rmsnorm_matmul(x: jax.Array, weight: jax.Array, w_proj: jax.Array, *,
 
     bm, bn, _ = _gemm.block_shape_for(mode, rows, n, d, x.dtype,
                                       plan_dialect)
-    bm = min(bm, align_up(rows, 128))
-    bn = min(bn, align_up(n, 128))
+    bm, bn, vmem = row_kernel_tiles(bm, bn, rows, d_padded, n,
+                                    jnp.dtype(x.dtype).itemsize)
     pad_m = (-rows) % bm
     pad_n = (-n) % bn
     if pad_m:
@@ -348,11 +393,8 @@ def rmsnorm_matmul(x: jax.Array, weight: jax.Array, w_proj: jax.Array, *,
             s2d = jnp.pad(s2d, ((0, 0), (0, pad_n)))
     mp, np_ = rows + pad_m, n + pad_n
     grid = (mp // bm, np_ // bn)
-
-    params = None
-    if mode == "native":
-        params = CompilerParams(
-            dimension_semantics=("parallel", "parallel"))
+    params = compiler_params(
+        ("parallel", "parallel") if mode == "native" else None, vmem)
 
     in_specs = [
         pl.BlockSpec((bm, d_padded), lambda i, j: (i, 0)),
@@ -405,13 +447,13 @@ def structural_cost_rmsnorm_matmul(rows: int, d: int, n: int, mode: str,
     if mode == "library":
         bm = bn = 512
     else:
-        # the kernel's own problem-size clamps, so block/steps/scratch
-        # report the executed tiling (re-read counts are unaffected: a
-        # clamp only fires when the tile already covers the dimension)
+        # the kernel's own fitted tiles, so block/steps/scratch report the
+        # executed tiling; the hbm terms keep the GEMM model's tile (a
+        # VMEM-halved tile re-reads the weight more than they count)
         bm, bn, _ = _gemm.block_shape_for(mode, rows, n, d, dtype,
                                           plan_dialect)
-        bm = min(bm, align_up(rows, 128))
-        bn = min(bn, align_up(n, 128))
+        bm, bn, _ = row_kernel_tiles(bm, bn, rows, _padded_d(d, mode), n,
+                                     itemsize)
     steps = -(-rows // bm) * -(-n // bn)
     if mode == "abstract":
         round_trips = tree_stages(LANES) + 1   # tree + moment re-stage
@@ -641,16 +683,17 @@ def _flash_matmul_kernel(*refs, scale: float,
                          has_pos: bool = False, paged: bool = False,
                          quant_w: bool = False, quant_kv: bool = False):
     # Operand order (optional members gated by the static flags):
-    #   [tbl,] q, k, [k_scale,] v, [v_scale,] w, [w_scale,] [pos]
-    # paged: the block table is the scalar-prefetch operand (consumed
-    # entirely by the kv index maps — the gather) and the per-slot
-    # frontier rides in as the (1, 1) pos block.  quant_kv: the kv blocks
+    #   [tbl,] [pos,] q, k, [k_scale,] v, [v_scale,] w, [w_scale,]
+    # tbl and pos are scalar-prefetch operands in SMEM.  paged: the block
+    # table is consumed entirely by the kv index maps (the gather); the
+    # per-slot frontier pos[b] masks the keys.  quant_kv: the kv blocks
     # arrive int8 with (block_kv, 1) per-token scales; quant_w: the wo
     # slice arrives int8 with a (1, n) per-channel scale row.  All
     # dequantization happens in VMEM, on blocks already resident.
     refs = list(refs)
     if paged:
         refs.pop(0)                               # block table (index maps)
+    pos_ref = refs.pop(0) if (paged or has_pos) else None
     q_ref = refs.pop(0)
     k_ref = refs.pop(0)
     k_scale_ref = refs.pop(0) if quant_kv else None
@@ -658,7 +701,6 @@ def _flash_matmul_kernel(*refs, scale: float,
     v_scale_ref = refs.pop(0) if quant_kv else None
     w_ref = refs.pop(0)
     ws_ref = refs.pop(0) if quant_w else None
-    pos_ref = refs.pop(0) if (paged or has_pos) else None
     o_ref, m_ref, l_ref, acc_ref, red_ref, oacc_ref = refs
     hh = pl.program_id(2)
 
@@ -692,7 +734,9 @@ def _flash_matmul_kernel(*refs, scale: float,
         scale=scale, causal=causal, kv_offset=kv_offset, block_q=block_q,
         block_kv=block_kv, n_kv=n_kv, mode=mode,
         skip=(mode == "native" and causal), kv_len=kv_len, q_axis=1,
-        kv_axis=3, epilogue=epilogue, pos_ref=pos_ref, skip_dead=paged,
+        kv_axis=3, epilogue=epilogue,
+        pos=None if pos_ref is None else pos_ref[pl.program_id(0)],
+        skip_dead=paged,
         k_scale_ref=k_scale_ref, v_scale_ref=v_scale_ref)
 
 
@@ -798,19 +842,19 @@ def flash_attention_matmul(q: jax.Array, k: jax.Array, v: jax.Array,
         w3 = jnp.pad(w3, ((0, 0), (0, 0), (0, n_p - n)))
     grid = (b, sqp // block_q, h, skvp // block_kv)
 
-    params = None
-    if mode == "native":
-        params = CompilerParams(dimension_semantics=(
-            "parallel", "parallel", "arbitrary", "arbitrary"))
-
+    # the decode frontier is a scalar-prefetch operand (SMEM): a (1, 1)
+    # VMEM block of a [B, 1] array breaks the (8, 128) tiling rule
+    prefetch = [] if pos is None else [pos.astype(jnp.int32)]
     in_specs = [
         pl.BlockSpec((1, 1, block_q, d),
-                     lambda bb, qi, hh, ki: (bb, hh, qi, 0)),
+                     lambda bb, qi, hh, ki, *_: (bb, hh, qi, 0)),
         pl.BlockSpec((1, 1, block_kv, d),
-                     lambda bb, qi, hh, ki, g=group: (bb, hh // g, ki, 0)),
+                     lambda bb, qi, hh, ki, *_, g=group:
+                     (bb, hh // g, ki, 0)),
         pl.BlockSpec((1, 1, block_kv, d),
-                     lambda bb, qi, hh, ki, g=group: (bb, hh // g, ki, 0)),
-        pl.BlockSpec((1, d, n_p), lambda bb, qi, hh, ki: (hh, 0, 0)),
+                     lambda bb, qi, hh, ki, *_, g=group:
+                     (bb, hh // g, ki, 0)),
+        pl.BlockSpec((1, d, n_p), lambda bb, qi, hh, ki, *_: (hh, 0, 0)),
     ]
     operands = [q_p, k_p, v_p, w3]
     if w_scale is not None:
@@ -818,24 +862,19 @@ def flash_attention_matmul(q: jax.Array, k: jax.Array, v: jax.Array,
         if n_p != n:
             s2d = jnp.pad(s2d, ((0, 0), (0, n_p - n)))
         in_specs.append(pl.BlockSpec((1, n_p),
-                                     lambda bb, qi, hh, ki: (0, 0)))
+                                     lambda bb, qi, hh, ki, *_: (0, 0)))
         operands.append(s2d)
-    if pos is not None:
-        in_specs.append(pl.BlockSpec((1, 1),
-                                     lambda bb, qi, hh, ki: (bb, 0)))
-        operands.append(pos.reshape(b, 1).astype(jnp.int32))
 
-    out = pl.pallas_call(
-        functools.partial(
-            _flash_matmul_kernel, scale=scale, causal=causal,
-            kv_offset=kv_offset, block_q=block_q, block_kv=block_kv,
-            n_kv=grid[3], n_heads=h, kv_len=skv, mode=mode,
-            has_pos=pos is not None, quant_w=w_scale is not None),
+    itemsize = jnp.dtype(q.dtype).itemsize
+    vmem = (2 * ((block_q + 2 * block_kv) * d + block_q * n_p) * itemsize
+            + 2 * d * n_p * jnp.dtype(w3.dtype).itemsize
+            + (block_q * (d + n_p + 2) + 8 * LANES) * 4)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, block_q, n_p),
-                               lambda bb, qi, hh, ki: (bb, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, sqp, n_p), q.dtype),
+                               lambda bb, qi, hh, ki, *_: (bb, qi, 0)),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),      # m
             pltpu.VMEM((block_q, 1), jnp.float32),      # l
@@ -844,10 +883,21 @@ def flash_attention_matmul(q: jax.Array, k: jax.Array, v: jax.Array,
                        else (8, LANES), jnp.float32),
             pltpu.VMEM((block_q, n_p), jnp.float32),    # cross-head acc
         ],
-        compiler_params=params,
+    )
+    out = pl.pallas_call(
+        functools.partial(
+            _flash_matmul_kernel, scale=scale, causal=causal,
+            kv_offset=kv_offset, block_q=block_q, block_kv=block_kv,
+            n_kv=grid[3], n_heads=h, kv_len=skv, mode=mode,
+            has_pos=pos is not None, quant_w=w_scale is not None),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, sqp, n_p), q.dtype),
+        compiler_params=compiler_params(
+            ("parallel", "parallel", "arbitrary", "arbitrary")
+            if mode == "native" else None, vmem),
         interpret=interpret,
         name=f"uisa_flash_attention_matmul_{mode.replace('+', '_')}",
-    )(*operands)
+    )(*prefetch, *operands)
     return out[:, :sq, :n]
 
 
@@ -920,22 +970,19 @@ def _paged_attention_matmul(q, k_pages, v_pages, w_out, *, block_tables,
         w3 = jnp.pad(w3, ((0, 0), (0, 0), (0, n_p - n)))
     grid = (b, sqp // bq, h, maxp)
 
-    params = None
-    if mode == "native":
-        params = CompilerParams(dimension_semantics=(
-            "parallel", "parallel", "arbitrary", "arbitrary"))
-
+    # the block table and the per-slot frontier are the scalar-prefetch
+    # operands (SMEM); the index maps receive both
     page_spec = pl.BlockSpec((1, 1, page_size, d),
-                             lambda bb, qi, hh, ki, tr, g=group:
+                             lambda bb, qi, hh, ki, tr, pr, g=group:
                              (tr[bb, ki], hh // g, 0, 0))
     # per-token scale pools ride the SAME table-gather index map as the
     # value pools — one scalar-prefetch plan covers both widths
     scale_spec = pl.BlockSpec((1, 1, page_size, 1),
-                              lambda bb, qi, hh, ki, tr, g=group:
+                              lambda bb, qi, hh, ki, tr, pr, g=group:
                               (tr[bb, ki], hh // g, 0, 0))
     in_specs = [
         pl.BlockSpec((1, 1, bq, d),
-                     lambda bb, qi, hh, ki, tr: (bb, hh, qi, 0)),
+                     lambda bb, qi, hh, ki, tr, pr: (bb, hh, qi, 0)),
         page_spec,
     ]
     operands = [q_p, k_pages]
@@ -948,25 +995,27 @@ def _paged_attention_matmul(q, k_pages, v_pages, w_out, *, block_tables,
         in_specs.append(scale_spec)
         operands.append(v_scale.astype(jnp.float32))
     in_specs.append(pl.BlockSpec((1, d, n_p),
-                                 lambda bb, qi, hh, ki, tr: (hh, 0, 0)))
+                                 lambda bb, qi, hh, ki, tr, pr: (hh, 0, 0)))
     operands.append(w3)
     if w_scale is not None:
         s2d = w_scale.reshape(1, n).astype(jnp.float32)
         if n_p != n:
             s2d = jnp.pad(s2d, ((0, 0), (0, n_p - n)))
         in_specs.append(pl.BlockSpec((1, n_p),
-                                     lambda bb, qi, hh, ki, tr: (0, 0)))
+                                     lambda bb, qi, hh, ki, tr, pr: (0, 0)))
         operands.append(s2d)
-    in_specs.append(pl.BlockSpec((1, 1),
-                                 lambda bb, qi, hh, ki, tr: (bb, 0)))
-    operands.append(pos.reshape(b, 1).astype(jnp.int32))
 
+    itemsize = jnp.dtype(q.dtype).itemsize
+    vmem = (2 * (bq * d + bq * n_p) * itemsize
+            + 2 * 2 * page_size * d * jnp.dtype(k_pages.dtype).itemsize
+            + 2 * d * n_p * jnp.dtype(w3.dtype).itemsize
+            + (bq * (d + n_p + 2) + 8 * LANES) * 4)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, bq, n_p),
-                               lambda bb, qi, hh, ki, tr: (bb, qi, 0)),
+                               lambda bb, qi, hh, ki, tr, pr: (bb, qi, 0)),
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),           # m
             pltpu.VMEM((bq, 1), jnp.float32),           # l
@@ -984,10 +1033,12 @@ def _paged_attention_matmul(q, k_pages, v_pages, w_out, *, block_tables,
             quant_w=w_scale is not None, quant_kv=k_scale is not None),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, sqp, n_p), q.dtype),
-        compiler_params=params,
+        compiler_params=compiler_params(
+            ("parallel", "parallel", "arbitrary", "arbitrary")
+            if mode == "native" else None, vmem),
         interpret=interpret,
         name=f"uisa_paged_attention_matmul_{mode.replace('+', '_')}",
-    )(tbl, *operands)
+    )(tbl, pos.astype(jnp.int32), *operands)
     return out[:, :sq, :n]
 
 
@@ -1193,11 +1244,11 @@ def _rmsnorm_swiglu_kernel(*refs, eps: float, mode: str, d_true: int,
     # both halves of the concatenated [wi|wg] weight consume the
     # normalized block from VMEM; the silu gate runs in the epilogue on
     # products that never left the core.
-    wi = wi_ref[...].astype(jnp.float32)
-    wg = wg_ref[...].astype(jnp.float32)
+    wi, wg = wi_ref[...], wg_ref[...]
     if si_ref is not None:
-        wi = wi * si_ref[...]                             # (1, bn) scales
-        wg = wg * sg_ref[...]
+        wi = wi.astype(jnp.float32) * si_ref[...]         # (1, bn) scales
+        wg = wg.astype(jnp.float32) * sg_ref[...]
+    y = y.astype(wi.dtype)              # the MXU takes the weight's width
     hi = jax.lax.dot_general(
         y, wi, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -1244,11 +1295,9 @@ def rmsnorm_swiglu(x: jax.Array, weight: jax.Array, w_cat: jax.Array, *,
         rows *= s
     x2d = x.reshape(rows, d)
     w2d = weight.reshape(1, d)
-    wi2d, wg2d = w_cat[:, :f], w_cat[:, f:]
-    si2d = sg2d = None
-    if w_scale is not None:
-        si2d = w_scale[:f].reshape(1, f).astype(jnp.float32)
-        sg2d = w_scale[f:].reshape(1, f).astype(jnp.float32)
+    wc2d = w_cat
+    sc2d = None if w_scale is None else \
+        w_scale.reshape(1, 2 * f).astype(jnp.float32)
 
     d_padded = d
     if mode != "native":
@@ -1257,47 +1306,48 @@ def rmsnorm_swiglu(x: jax.Array, weight: jax.Array, w_cat: jax.Array, *,
             d_padded = d + pad_d
             x2d = jnp.pad(x2d, ((0, 0), (0, pad_d)))
             w2d = jnp.pad(w2d, ((0, 0), (0, pad_d)))
-            wi2d = jnp.pad(wi2d, ((0, pad_d), (0, 0)))
-            wg2d = jnp.pad(wg2d, ((0, pad_d), (0, 0)))
+            wc2d = jnp.pad(wc2d, ((0, pad_d), (0, 0)))
 
     bm, bn = resolve_swiglu_blocks(mode, rows, d, f, x.dtype, plan_dialect,
                                    op=tuning_op)
-    bm = min(bm, align_up(rows, 128))
-    bn = min(bn, align_up(f, 128))
+    bm, bn, vmem = row_kernel_tiles(bm, bn, rows, d_padded, f,
+                                    jnp.dtype(x.dtype).itemsize, n_weights=2)
     pad_m = (-rows) % bm
-    pad_n = (-f) % bn
     if pad_m:
         x2d = jnp.pad(x2d, ((0, pad_m), (0, 0)))
+    pad_n = (-f) % bn
     if pad_n:
-        wi2d = jnp.pad(wi2d, ((0, 0), (0, pad_n)))
-        wg2d = jnp.pad(wg2d, ((0, 0), (0, pad_n)))
-        if si2d is not None:
-            si2d = jnp.pad(si2d, ((0, 0), (0, pad_n)))
-            sg2d = jnp.pad(sg2d, ((0, 0), (0, pad_n)))
+        # pad each half to the tile, so wg's columns start on a tile
+        # boundary; when f divides, [wi|wg] is read in place, uncopied
+        def pad_halves(a):
+            pad = ((0, 0), (0, pad_n))
+            return jnp.concatenate([jnp.pad(a[:, :f], pad),
+                                    jnp.pad(a[:, f:], pad)], axis=1)
+        wc2d = pad_halves(wc2d)
+        if sc2d is not None:
+            sc2d = pad_halves(sc2d)
     mp, fp = rows + pad_m, f + pad_n
     grid = (mp // bm, fp // bn)
+    params = compiler_params(
+        ("parallel", "parallel") if mode == "native" else None, vmem)
 
-    params = None
-    if mode == "native":
-        params = CompilerParams(
-            dimension_semantics=("parallel", "parallel"))
-
+    wg_off = fp // bn               # wg's first column block in [wi|wg]
     in_specs = [
         pl.BlockSpec((bm, d_padded), lambda i, j: (i, 0)),
         pl.BlockSpec((1, d_padded), lambda i, j: (0, 0)),
         pl.BlockSpec((d_padded, bn), lambda i, j: (0, j)),
-        pl.BlockSpec((d_padded, bn), lambda i, j: (0, j)),
+        pl.BlockSpec((d_padded, bn), lambda i, j: (0, j + wg_off)),
     ]
-    operands = [x2d, w2d, wi2d, wg2d]
-    if si2d is not None:
+    operands = [x2d, w2d, wc2d, wc2d]
+    if sc2d is not None:
         in_specs += [pl.BlockSpec((1, bn), lambda i, j: (0, j)),
-                     pl.BlockSpec((1, bn), lambda i, j: (0, j))]
-        operands += [si2d, sg2d]
-    kernel_name = ("uisa_rmsnorm_swiglu_q8_" if si2d is not None
+                     pl.BlockSpec((1, bn), lambda i, j: (0, j + wg_off))]
+        operands += [sc2d, sc2d]
+    kernel_name = ("uisa_rmsnorm_swiglu_q8_" if sc2d is not None
                    else "uisa_rmsnorm_swiglu_") + mode.replace('+', '_')
     out = pl.pallas_call(
         functools.partial(_rmsnorm_swiglu_kernel, eps=eps, mode=mode,
-                          d_true=d, quant=si2d is not None),
+                          d_true=d, quant=sc2d is not None),
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
@@ -1335,8 +1385,8 @@ def structural_cost_rmsnorm_swiglu(rows: int, d: int, f: int, mode: str,
     else:
         bm, bn = resolve_swiglu_blocks(mode, rows, d, f, dtype,
                                        plan_dialect, op=op)
-        bm = min(bm, align_up(rows, 128))
-        bn = min(bn, align_up(f, 128))
+        bm, bn, _ = row_kernel_tiles(bm, bn, rows, _padded_d(d, mode), f,
+                                     itemsize, n_weights=2)
     steps = -(-rows // bm) * -(-f // bn)
     if mode == "abstract":
         round_trips = tree_stages(LANES) + 1   # tree + moment re-stage

@@ -33,7 +33,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import (IsaMode, KernelContract, Primitive, REGISTRY,
                         TARGET, UNIVERSAL_SET, align_up, choose_block_bytes,
                         register_op_space, tuned_block, validate_contract)
-from repro.core.pipeline import CompilerParams
+from repro.core.pipeline import compiler_params
 
 register_op_space("gemm", "gemm")
 
@@ -137,19 +137,17 @@ def gemm(a: jax.Array, b: jax.Array, *, mode: str = "native",
     if mode == "library":
         return jnp.dot(a, b, preferred_element_type=jnp.float32).astype(out_dtype)
 
-    if mode in ("abstract", "abstract+shuffle"):
-        bm, bn, bk = block_shape_for(mode, m, n, k, a.dtype, plan_dialect)
-        params = None
-    elif mode == "native":
-        bm, bn, bk = block_shape_for(mode, m, n, k, a.dtype, plan_dialect)
-        params = CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    else:
+    if mode not in ("abstract", "abstract+shuffle", "native"):
         raise ValueError(f"unknown isa mode {mode!r}")
-
+    bm, bn, bk = block_shape_for(mode, m, n, k, a.dtype, plan_dialect)
     # cap blocks at the (tile-rounded) problem size for small inputs
     bm, bn, bk = (min(bm, align_up(m, 128)), min(bn, align_up(n, 128)),
                   min(bk, align_up(k, 128)))
+    # double-buffered A/B/C blocks plus the f32 accumulator
+    vmem = (2 * (bm * bk + bk * bn) * jnp.dtype(a.dtype).itemsize
+            + 2 * bm * bn * jnp.dtype(out_dtype).itemsize + bm * bn * 4)
+    params = compiler_params(("parallel", "parallel", "arbitrary")
+                             if mode == "native" else None, vmem)
     a_p = _pad_to(a, bm, bk)
     b_p = _pad_to(b, bk, bn)
     mp, kp = a_p.shape

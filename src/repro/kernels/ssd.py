@@ -54,7 +54,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import (IsaMode, KernelContract, Primitive, REGISTRY)
-from repro.core.pipeline import CompilerParams
+from repro.core.pipeline import compiler_params
 from repro.core.shuffle import (LANES, lane_shuffle_up, lane_tree_reduce,
                                 scratch_tree_reduce)
 from repro.core.tuning import (active_dialect, register_op_space,
@@ -333,10 +333,8 @@ def fused_ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array,
     h0h = h0.reshape(b, h, n, p)
 
     grid = (b, h, nc)                                 # chunk axis last
-    params = None
-    if mode == "native":
-        params = CompilerParams(dimension_semantics=(
-            "parallel", "parallel", "arbitrary"))
+    params = compiler_params(("parallel", "parallel", "arbitrary")
+                             if mode == "native" else None)
 
     y, hf = pl.pallas_call(
         functools.partial(_ssd_scan_kernel, q=q, n_chunks=nc, mode=mode),
@@ -473,10 +471,8 @@ def fused_ssd_decode(state: jax.Array, x_t: jax.Array, dt_t: jax.Array,
     bp = b + pad
 
     grid = (bp // bb, h)
-    params = None
-    if mode == "native":
-        params = CompilerParams(dimension_semantics=(
-            "parallel", "parallel"))
+    params = compiler_params(("parallel", "parallel")
+                             if mode == "native" else None)
 
     y, hf = pl.pallas_call(
         functools.partial(_ssd_decode_kernel, bb=bb, n=n, p=p, mode=mode),

@@ -49,7 +49,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
     try:
         cell = cells_lib.build_cell(arch, shape_name, mesh,
                                     par_overrides=par_overrides)
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = cell.lower()
             t_lower = time.time() - t0
             compiled = lowered.compile()

@@ -14,7 +14,7 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from repro.models.config import ParallelConfig
 from repro.parallel.sharding import ShardCtx
@@ -23,12 +23,18 @@ from repro.parallel.sharding import ShardCtx
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    """Arbitrary mesh (tests use (1,1)/(2,2); elastic restarts resize)."""
-    return jax.make_mesh(shape, axes)
+    """Arbitrary mesh (tests use (1,1)/(2,2); elastic restarts resize).
+
+    Every axis is ``Auto``: the model places data with logical-axis
+    ``with_sharding_constraint`` calls and lets the partitioner propagate
+    the rest (a vocab-sharded embedding gather, say).  ``jax.make_mesh``
+    defaults to ``Explicit`` axes, under which such ops demand an
+    ``out_sharding`` at every call site."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_ctx(mesh: Optional[Mesh], par: ParallelConfig,

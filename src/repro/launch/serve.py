@@ -1,7 +1,10 @@
 """Serving driver: batched engine with continuous batching.
 
   PYTHONPATH=src python -m repro.launch.serve \\
-      --arch granite-8b --reduced --slots 4 --requests 10 --max-new 16
+      --arch granite-8b --slots 4 --requests 10 --max-new 16
+
+The reduced config is the default; ``--no-reduced`` serves the published
+widths.
 """
 import argparse
 import time
@@ -10,6 +13,7 @@ import jax
 import numpy as np
 
 from repro import configs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.models.config import ParallelConfig
 from repro.serve import BatchedEngine, Request, ServeConfig
@@ -18,7 +22,8 @@ from repro.serve import BatchedEngine, Request, ServeConfig
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--requests", type=int, default=10)
     ap.add_argument("--prompt-len", type=int, default=12)
@@ -26,6 +31,7 @@ def main():
     ap.add_argument("--max-seq", type=int, default=64)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = (configs.get_reduced(args.arch) if args.reduced
            else configs.get_config(args.arch))
@@ -50,7 +56,8 @@ def main():
     dt = time.perf_counter() - t0
     total_tokens = sum(len(r.generated) for r in done)
     print(f"[serve] {len(done)} requests, {total_tokens} tokens in "
-          f"{dt:.2f}s ({total_tokens / dt:.1f} tok/s on CPU)")
+          f"{dt:.2f}s ({total_tokens / dt:.1f} tok/s on "
+          f"{jax.devices()[0].device_kind}, compile included)")
     for r in done[:3]:
         print(f"  rid={r.rid} generated={r.generated[:8]}...")
 

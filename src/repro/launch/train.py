@@ -24,6 +24,7 @@ import numpy as np
 from repro import configs
 from repro.checkpoint import CheckpointManager
 from repro.data import DataConfig, SyntheticLMDataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_ctx, make_mesh
 from repro.models import build_model
 from repro.models.config import ParallelConfig
@@ -54,6 +55,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--report", default=None, help="write JSON report here")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = (configs.get_reduced(args.arch) if args.reduced
            else configs.get_config(args.arch))

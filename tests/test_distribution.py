@@ -109,7 +109,7 @@ class TestCellBuilder:
         sc = ShapeConfig("train_4k", "train", 32, 4)
         cell = build_cell("granite-8b", "train_4k", mesh, reduced=True,
                           shape_cfg=sc)
-        with mesh:
+        with jax.set_mesh(mesh):
             compiled = cell.lower().compile()
         assert compiled.cost_analysis() is not None
 
@@ -228,7 +228,7 @@ class TestDryRunSubprocess:
             "    sc = ShapeConfig('train_4k', 'train', 64, 32)\n"
             "    cell = build_cell('granite-8b', 'train_4k', mesh,\n"
             "                      reduced=True, shape_cfg=sc)\n"
-            "    with mesh:\n"
+            "    with jax.set_mesh(mesh):\n"
             "        compiled = cell.lower().compile()\n"
             "    assert compiled is not None\n"
             "print('DRYRUN_SMOKE_OK')\n")

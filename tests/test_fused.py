@@ -386,3 +386,57 @@ class TestBenchArtifact:
         finally:
             sys.path.pop(0)
         assert compare(data, data) == []
+
+
+# ---------------------------------------------------------------------------
+# Norm-prologue tiles: fitted to the problem and to the chip's VMEM
+# ---------------------------------------------------------------------------
+
+
+class TestRowKernelTiles:
+    """granite-8b's hot spots (d_model 4096; qkv 6144, [wi|wg] 2 x 14336
+    and head 49152 columns; prefill, decode and head row counts)."""
+
+    SHAPES = [(2048, 6144, 1), (16, 6144, 1), (1, 49152, 1),
+              (2048, 14336, 2), (16, 14336, 2)]
+
+    @pytest.mark.parametrize("rows,n,n_weights", SHAPES)
+    @pytest.mark.parametrize("itemsize", (2, 4))
+    def test_no_padding_past_the_lanes(self, rows, n, n_weights, itemsize):
+        from repro.kernels.fused import row_kernel_tiles
+        bm, bn, _ = row_kernel_tiles(1152, 1152, rows, 4096, n, itemsize,
+                                     n_weights)
+        assert bm % 128 == 0 and bn % 128 == 0
+        assert -(-rows // 128) * 128 % bm == 0
+        assert n % bn == 0
+
+    @pytest.mark.parametrize("rows,n,n_weights", SHAPES)
+    def test_one_stage_keeps_eq1_occupancy(self, rows, n, n_weights):
+        """Double-buffered, one stage fits twice in the target's VMEM:
+        half of it stays free for the kernel's f32 temporaries."""
+        from repro.kernels.fused import row_kernel_tiles
+        _, _, vmem = row_kernel_tiles(1024, 1024, rows, 4096, n, 2,
+                                      n_weights)
+        assert TARGET.buffer_occupancy(vmem // 2, 2) >= 2
+
+    def test_vmem_limit_raised_only_past_the_default(self):
+        from repro.core.pipeline import DEFAULT_SCOPED_VMEM, compiler_params
+        assert compiler_params(None, DEFAULT_SCOPED_VMEM) is None
+        raised = compiler_params(None, DEFAULT_SCOPED_VMEM + 1)
+        assert raised.vmem_limit_bytes == TARGET.scratchpad_bytes
+        assert raised.dimension_semantics is None
+        native = compiler_params(("parallel",))
+        assert native.dimension_semantics == ("parallel",)
+        assert native.vmem_limit_bytes is None
+
+    @pytest.mark.parametrize("f", (256, 200))
+    def test_swiglu_reads_both_halves_of_one_operand(self, f):
+        """wi and wg are two column windows of the one [wi|wg] operand;
+        a width that does not divide the tile is padded per half."""
+        x, w, _, _ = _inputs(rows=24, d=128)
+        wc = jax.random.normal(KEY, (128, 2 * f), jnp.float32)
+        got = ops.fused_rmsnorm_swiglu(x[:, :128], w[:128], wc,
+                                       mode="native")
+        want = ops.fused_rmsnorm_swiglu(x[:, :128], w[:128], wc,
+                                        mode="library")
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)

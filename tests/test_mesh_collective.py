@@ -4,9 +4,9 @@ subprocess probe runs under ``REPRO_SIM_DEVICES=4`` (the hostdev helper
 installs ``--xla_force_host_platform_device_count`` before jax wakes
 up) and reports JSON; the tests here pin its numbers:
 
-- ``ambient_mesh_axes`` falls back to an *entered* ``jax.sharding.Mesh``
-  (not just the contextvar), so ``"auto"`` retargets to the TP twin
-  inside a plain ``with mesh:`` block.
+- ``ambient_mesh_axes`` falls back to a mesh entered with
+  ``jax.set_mesh`` (not just the contextvar), so ``"auto"`` retargets to
+  the TP twin inside a plain ``with jax.set_mesh(mesh):`` block.
 - Acceptance: ``roofline.analysis.parse_collectives`` on a really
   lowered TP program reports exactly the wire bytes
   ``core.dialect.collective_cost`` models (``collective_bytes``) — the
@@ -38,7 +38,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 out = {"installed": installed, "n_devices": jax.device_count()}
 
@@ -46,10 +45,11 @@ from repro.core.registry import (AUTO_POLICY, REGISTRY, ambient_mesh_axes,
                                  tp_axis_size)
 from repro.kernels import ops  # noqa: F401  (installs every variant)
 from repro.roofline.analysis import parse_collectives
+from repro.launch.mesh import make_ctx, make_mesh
 
-mesh4 = jax.make_mesh((4,), ("model",))
+mesh4 = make_mesh((4,), ("model",))
 out["ambient_no_mesh"] = ambient_mesh_axes()
-with mesh4:
+with jax.set_mesh(mesh4):
     out["ambient"] = ambient_mesh_axes()
     out["tp"] = tp_axis_size()
     out["auto_kernel"] = REGISTRY.select(
@@ -63,24 +63,23 @@ M, K, N = __M__, __K__, __N__
 x = jnp.ones((M, K), jnp.float32)
 w = jnp.ones((K, N), jnp.float32)
 
-col = shard_map(                  # column-parallel: all-gather the output
+col = jax.shard_map(                  # column-parallel: all-gather the output
     lambda x, w: jax.lax.all_gather(x @ w, "model", axis=1, tiled=True),
     mesh=mesh4, in_specs=(P(None, None), P(None, "model")),
-    out_specs=P(None, None), check_rep=False)
+    out_specs=P(None, None), check_vma=False)
 out["col"] = parse_collectives(
     jax.jit(col).lower(x, w).compile().as_text(), 4)
 
-row = shard_map(                  # row-parallel: all-reduce the partials
+row = jax.shard_map(                  # row-parallel: all-reduce the partials
     lambda x, w: jax.lax.psum(x @ w, "model"),
     mesh=mesh4, in_specs=(P(None, "model"), P("model", None)),
-    out_specs=P(None, None), check_rep=False)
+    out_specs=P(None, None), check_vma=False)
 out["row"] = parse_collectives(
     jax.jit(row).lower(x, w).compile().as_text(), 4)
 
 # --- qkv_heads layout equivalence ---
 from repro.models import build_model
 from repro.models.config import ModelConfig, ParallelConfig
-from repro.launch.mesh import make_ctx, make_mesh
 from repro.parallel.sharding import sanitize_tree, tree_shardings
 
 cfg = ModelConfig(name="t", family="dense", num_layers=2, d_model=64,
@@ -104,7 +103,7 @@ for shape in [(2, 2), (1, 4)]:
         lambda x, s: jax.device_put(x, s) if s is not None else x,
         params, sh,
         is_leaf=lambda v: v is None or not isinstance(v, (dict, list)))
-    with mesh:
+    with jax.set_mesh(mesh):
         lg = np.asarray(model_tp.prefill(p_sh, {"tokens": toks})[0])
     out[f"qkv_maxdiff_{t}way"] = float(np.abs(lg - ref_logits).max())
 
@@ -134,7 +133,7 @@ class TestAmbientMesh:
         assert probe["installed"] == 4 and probe["n_devices"] == 4
 
     def test_entered_mesh_is_the_ambient_fallback(self, probe):
-        """No contextvar set: a plain ``with mesh:`` block is enough for
+        """No contextvar set: a plain ``jax.set_mesh`` block is enough for
         the registry to see the axes (and nothing leaks outside it)."""
         assert probe["ambient_no_mesh"] == {}
         assert probe["ambient"] == {"model": 4}

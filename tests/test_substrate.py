@@ -348,3 +348,33 @@ class TestEngine:
             want.append(nxt)
             tok = jnp.array([nxt], jnp.int32)
         assert req.generated == want
+
+
+class TestCompileCache:
+    """The persistent compilation cache is placed from outside: the
+    environment variable when set, else one fixed path in the checkout."""
+
+    def _restore(self, fn):
+        was = jax.config.jax_compilation_cache_dir
+        try:
+            return fn(), jax.config.jax_compilation_cache_dir
+        finally:
+            jax.config.update("jax_compilation_cache_dir", was)
+
+    def test_env_var_wins_and_nothing_is_set(self, monkeypatch):
+        from repro.launch import compile_cache
+        monkeypatch.setenv(compile_cache.ENV_VAR, "/elsewhere/cache")
+        before = jax.config.jax_compilation_cache_dir
+        used, after = self._restore(compile_cache.enable_compile_cache)
+        assert used == "/elsewhere/cache"
+        assert after == before
+
+    def test_fixed_ignored_path_in_checkout(self, monkeypatch):
+        from repro.launch import compile_cache
+        monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+        used, after = self._restore(compile_cache.enable_compile_cache)
+        again, _ = self._restore(compile_cache.enable_compile_cache)
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert used == after == again == os.path.join(root, ".jax_cache")
+        with open(os.path.join(root, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()
