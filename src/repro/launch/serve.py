@@ -7,7 +7,6 @@ The reduced config is the default; ``--no-reduced`` serves the published
 widths.
 """
 import argparse
-import time
 
 import jax
 import numpy as np
@@ -51,13 +50,9 @@ def main():
                     max_new_tokens=args.max_new)
             for i in range(args.requests)]
 
-    t0 = time.perf_counter()
     done = engine.run(reqs)
-    dt = time.perf_counter() - t0
     total_tokens = sum(len(r.generated) for r in done)
-    print(f"[serve] {len(done)} requests, {total_tokens} tokens in "
-          f"{dt:.2f}s ({total_tokens / dt:.1f} tok/s on "
-          f"{jax.devices()[0].device_kind}, compile included)")
+    print(f"[serve] {len(done)} requests, {total_tokens} tokens")
     for r in done[:3]:
         print(f"  rid={r.rid} generated={r.generated[:8]}...")
 

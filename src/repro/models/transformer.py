@@ -258,60 +258,70 @@ def attn_decode(params, x_t, cfg: ModelConfig, kv_cache, pos, ctx,
     q, k_new, v_new = _project_qkv(params, x_t, cfg, positions, ctx,
                                    policy=policy, norm_scale=norm_scale)
     if block_tables is not None:
-        if int8:
-            # int8 paged cache: quantize-on-write through the same table
-            # scatter, per-page scales riding parallel [P,Hkv,ps,1] pools.
-            k_pages, k_sc, v_pages, v_sc = kv_cache
-            k_pages, k_sc = update_paged_cache_int8(k_pages, k_sc, k_new,
-                                                    block_tables, pos)
-            v_pages, v_sc = update_paged_cache_int8(v_pages, v_sc, v_new,
-                                                    block_tables, pos)
-            new_cache = (k_pages, k_sc, v_pages, v_sc)
-        else:
-            k_pages, v_pages = kv_cache
-            k_pages = update_paged_cache(k_pages, k_new, block_tables, pos)
-            v_pages = update_paged_cache(v_pages, v_new, block_tables, pos)
-            k_sc = v_sc = None
-            new_cache = (k_pages, v_pages)
+        with jax.named_scope("kv_write"):
+            if int8:
+                # int8 paged cache: quantize-on-write through the same
+                # table scatter, per-page scales riding parallel
+                # [P,Hkv,ps,1] pools.
+                k_pages, k_sc, v_pages, v_sc = kv_cache
+                k_pages, k_sc = update_paged_cache_int8(
+                    k_pages, k_sc, k_new, block_tables, pos)
+                v_pages, v_sc = update_paged_cache_int8(
+                    v_pages, v_sc, v_new, block_tables, pos)
+                new_cache = (k_pages, k_sc, v_pages, v_sc)
+            else:
+                k_pages, v_pages = kv_cache
+                k_pages = update_paged_cache(k_pages, k_new, block_tables,
+                                             pos)
+                v_pages = update_paged_cache(v_pages, v_new, block_tables,
+                                             pos)
+                k_sc = v_sc = None
+                new_cache = (k_pages, v_pages)
         if fuse_wo:
             from repro.kernels import ops as kernel_ops
-            out = kernel_ops.fused_flash_attention_matmul(
-                q, k_pages, v_pages, params["wo"], pos=pos,
-                block_tables=block_tables,
-                policy=policy.kernel() if policy is not None else None,
-                w_scale=params.get("wo_scale"), k_scale=k_sc, v_scale=v_sc)
+            with jax.named_scope("kv_read"):
+                out = kernel_ops.fused_flash_attention_matmul(
+                    q, k_pages, v_pages, params["wo"], pos=pos,
+                    block_tables=block_tables,
+                    policy=policy.kernel() if policy is not None else None,
+                    w_scale=params.get("wo_scale"), k_scale=k_sc,
+                    v_scale=v_sc)
             return out, new_cache
-        if int8:
-            # unfused reference path: dequantize the gathered-from pools
-            # up front (the fused kernel instead dequantizes per page, in
-            # VMEM, only for live table entries)
-            k_pages = dequantize_kv(k_pages, k_sc, x_t.dtype)
-            v_pages = dequantize_kv(v_pages, v_sc, x_t.dtype)
-        o = paged_decode_attention(q, k_pages, v_pages, block_tables, pos,
-                                   ctx=ctx)
+        with jax.named_scope("kv_read"):
+            if int8:
+                # unfused reference path: dequantize the gathered-from
+                # pools up front (the fused kernel instead dequantizes per
+                # page, in VMEM, only for live table entries)
+                k_pages = dequantize_kv(k_pages, k_sc, x_t.dtype)
+                v_pages = dequantize_kv(v_pages, v_sc, x_t.dtype)
+            o = paged_decode_attention(q, k_pages, v_pages, block_tables,
+                                       pos, ctx=ctx)
         o = o.transpose(0, 2, 1, 3).reshape(b, 1, -1)
         out = jnp.einsum("bsh,hd->bsd", o, _wo_weight(params, x_t.dtype))
         return out, new_cache
-    if int8:
-        k_q, k_s, v_q, v_s = kv_cache
-        k_q, k_s = update_cache_int8(k_q, k_s, k_new, pos)
-        v_q, v_s = update_cache_int8(v_q, v_s, v_new, pos)
-        k_cache = dequantize_kv(k_q, k_s, x_t.dtype)
-        v_cache = dequantize_kv(v_q, v_s, x_t.dtype)
-        new_cache = (k_q, k_s, v_q, v_s)
-    else:
-        k_cache, v_cache = kv_cache
-        k_cache = update_cache(k_cache, k_new, pos)
-        v_cache = update_cache(v_cache, v_new, pos)
-        new_cache = (k_cache, v_cache)
-    if fuse_wo:
-        from repro.kernels import ops as kernel_ops
-        out = kernel_ops.fused_flash_attention_matmul(
-            q, k_cache, v_cache, params["wo"], pos=pos,
-            policy=policy.kernel() if policy is not None else None,
-            w_scale=params.get("wo_scale"))
-        return out, new_cache
-    o = decode_attention(q, k_cache, v_cache, pos, ctx=ctx)
+    with jax.named_scope("kv_write"):
+        if int8:
+            k_q, k_s, v_q, v_s = kv_cache
+            k_q, k_s = update_cache_int8(k_q, k_s, k_new, pos)
+            v_q, v_s = update_cache_int8(v_q, v_s, v_new, pos)
+            new_cache = (k_q, k_s, v_q, v_s)
+        else:
+            k_cache, v_cache = kv_cache
+            k_cache = update_cache(k_cache, k_new, pos)
+            v_cache = update_cache(v_cache, v_new, pos)
+            new_cache = (k_cache, v_cache)
+    with jax.named_scope("kv_read"):
+        if int8:
+            k_cache = dequantize_kv(k_q, k_s, x_t.dtype)
+            v_cache = dequantize_kv(v_q, v_s, x_t.dtype)
+        if fuse_wo:
+            from repro.kernels import ops as kernel_ops
+            out = kernel_ops.fused_flash_attention_matmul(
+                q, k_cache, v_cache, params["wo"], pos=pos,
+                policy=policy.kernel() if policy is not None else None,
+                w_scale=params.get("wo_scale"))
+            return out, new_cache
+        o = decode_attention(q, k_cache, v_cache, pos, ctx=ctx)
     o = o.transpose(0, 2, 1, 3).reshape(b, 1, -1)
     out = jnp.einsum("bsh,hd->bsd", o, _wo_weight(params, x_t.dtype))
     return out, new_cache
@@ -330,20 +340,21 @@ def block_seq(params, x, cfg: ModelConfig, par: ParallelConfig, positions,
     # the two per-sublayer activation round trips the unfused sequence
     # stages through HBM (see kernels/fused.py).
     fuse = policy.fuses() and cfg.norm == "rmsnorm"
-    if fuse:
-        h, norm_scale = x, params["ln1"]["scale"]
-    else:
-        h = common.apply_norm(x, params["ln1"], cfg.norm, cfg.norm_eps,
-                              policy=policy)
-        norm_scale = None
-    if return_kv:
-        a, kv = attn_seq(params["attn"], h, cfg, par, positions, ctx,
-                         return_kv=True, policy=policy,
-                         norm_scale=norm_scale)
-    else:
-        a = attn_seq(params["attn"], h, cfg, par, positions, ctx,
-                     policy=policy, norm_scale=norm_scale)
-        kv = None
+    with jax.named_scope("attention"):
+        if fuse:
+            h, norm_scale = x, params["ln1"]["scale"]
+        else:
+            h = common.apply_norm(x, params["ln1"], cfg.norm, cfg.norm_eps,
+                                  policy=policy)
+            norm_scale = None
+        if return_kv:
+            a, kv = attn_seq(params["attn"], h, cfg, par, positions, ctx,
+                             return_kv=True, policy=policy,
+                             norm_scale=norm_scale)
+        else:
+            a = attn_seq(params["attn"], h, cfg, par, positions, ctx,
+                         policy=policy, norm_scale=norm_scale)
+            kv = None
     # ln2 routing: when a fusable wi/wg pair sits downstream (dense silu
     # MLP, or a MoE with shared experts), the fused path keeps the
     # residual RAW — ln2 rides into the wi/wg projections as a fused
@@ -354,29 +365,30 @@ def block_seq(params, x, cfg: ModelConfig, par: ParallelConfig, positions,
     # fusion stays.
     swiglu_fuse = (fuse and cfg.act == "silu"
                    and (cfg.moe is None or bool(cfg.moe.shared_experts)))
-    if swiglu_fuse:
-        x = x + a
-        h, mlp_scale = x, params["ln2"]["scale"]
-    elif fuse:
-        h, x = common.add_rmsnorm(x, a, params["ln2"]["scale"],
-                                  cfg.norm_eps, policy=policy)
-        mlp_scale = None
-    else:
-        x = x + a
-        h = common.apply_norm(x, params["ln2"], cfg.norm, cfg.norm_eps,
-                              policy=policy)
-        mlp_scale = None
-    if cfg.moe is not None:
-        m, aux = mlp.apply_moe(params["moe"], h, cfg.moe, cfg.act, ctx,
-                               policy=policy, norm_scale=mlp_scale,
-                               eps=cfg.norm_eps)
-    else:
-        m, aux = mlp.apply_mlp(params["mlp"], h, cfg.act, ctx,
-                               policy=policy, norm_scale=mlp_scale,
-                               eps=cfg.norm_eps), 0.0
-    if par.rs_outputs:
-        m = shard(m, ("act_batch", "act_seq", "act_embed"), ctx)
-    x = x + m
+    with jax.named_scope("mlp"):
+        if swiglu_fuse:
+            x = x + a
+            h, mlp_scale = x, params["ln2"]["scale"]
+        elif fuse:
+            h, x = common.add_rmsnorm(x, a, params["ln2"]["scale"],
+                                      cfg.norm_eps, policy=policy)
+            mlp_scale = None
+        else:
+            x = x + a
+            h = common.apply_norm(x, params["ln2"], cfg.norm, cfg.norm_eps,
+                                  policy=policy)
+            mlp_scale = None
+        if cfg.moe is not None:
+            m, aux = mlp.apply_moe(params["moe"], h, cfg.moe, cfg.act, ctx,
+                                   policy=policy, norm_scale=mlp_scale,
+                                   eps=cfg.norm_eps)
+        else:
+            m, aux = mlp.apply_mlp(params["mlp"], h, cfg.act, ctx,
+                                   policy=policy, norm_scale=mlp_scale,
+                                   eps=cfg.norm_eps), 0.0
+        if par.rs_outputs:
+            m = shard(m, ("act_batch", "act_seq", "act_embed"), ctx)
+        x = x + m
     x = shard(x, ("act_batch", "act_seq", "act_embed"), ctx)
     return (x, aux, kv) if return_kv else (x, aux)
 
@@ -396,16 +408,17 @@ def block_decode(params, x_t, cfg: ModelConfig, kv_cache, pos, ctx,
     # which is exactly the PR 4 behavior.  The activation-sized
     # residual→norm fusion has no weight term and is layout-independent.
     qkv_fuse = fuse and common.stored_concat(params["attn"], "wqkv")
-    if qkv_fuse:
-        h, ln1_scale = x_t, params["ln1"]["scale"]
-    else:
-        h = common.apply_norm(x_t, params["ln1"], cfg.norm, cfg.norm_eps,
-                              policy=policy)
-        ln1_scale = None
-    a, kv_cache = attn_decode(params["attn"], h, cfg, kv_cache, pos, ctx,
-                              int8=int8, policy=policy,
-                              norm_scale=ln1_scale, fuse_wo=fuse_wo,
-                              block_tables=block_tables)
+    with jax.named_scope("attention"):
+        if qkv_fuse:
+            h, ln1_scale = x_t, params["ln1"]["scale"]
+        else:
+            h = common.apply_norm(x_t, params["ln1"], cfg.norm,
+                                  cfg.norm_eps, policy=policy)
+            ln1_scale = None
+        a, kv_cache = attn_decode(params["attn"], h, cfg, kv_cache, pos,
+                                  ctx, int8=int8, policy=policy,
+                                  norm_scale=ln1_scale, fuse_wo=fuse_wo,
+                                  block_tables=block_tables)
     if cfg.moe is None:
         mlp_params = params["mlp"]
     elif cfg.moe.shared_experts:
@@ -414,26 +427,29 @@ def block_decode(params, x_t, cfg: ModelConfig, kv_cache, pos, ctx,
         mlp_params = {}                  # router-only MoE: no fusable pair
     swiglu_fuse = (fuse and cfg.act == "silu"
                    and common.stored_concat(mlp_params, "wig"))
-    if swiglu_fuse:
-        x_t = x_t + a
-        h, mlp_scale = x_t, params["ln2"]["scale"]
-    elif fuse:
-        h, x_t = common.add_rmsnorm(x_t, a, params["ln2"]["scale"],
-                                    cfg.norm_eps, policy=policy)
-        mlp_scale = None
-    else:
-        x_t = x_t + a
-        h = common.apply_norm(x_t, params["ln2"], cfg.norm, cfg.norm_eps,
-                              policy=policy)
-        mlp_scale = None
-    if cfg.moe is not None:
-        m, _ = mlp.apply_moe(params["moe"], h, cfg.moe, cfg.act, ctx,
-                             policy=policy, norm_scale=mlp_scale,
-                             eps=cfg.norm_eps)
-    else:
-        m = mlp.apply_mlp(params["mlp"], h, cfg.act, ctx, policy=policy,
-                          norm_scale=mlp_scale, eps=cfg.norm_eps)
-    return x_t + m, kv_cache
+    with jax.named_scope("mlp"):
+        if swiglu_fuse:
+            x_t = x_t + a
+            h, mlp_scale = x_t, params["ln2"]["scale"]
+        elif fuse:
+            h, x_t = common.add_rmsnorm(x_t, a, params["ln2"]["scale"],
+                                        cfg.norm_eps, policy=policy)
+            mlp_scale = None
+        else:
+            x_t = x_t + a
+            h = common.apply_norm(x_t, params["ln2"], cfg.norm,
+                                  cfg.norm_eps, policy=policy)
+            mlp_scale = None
+        if cfg.moe is not None:
+            m, _ = mlp.apply_moe(params["moe"], h, cfg.moe, cfg.act, ctx,
+                                 policy=policy, norm_scale=mlp_scale,
+                                 eps=cfg.norm_eps)
+        else:
+            m = mlp.apply_mlp(params["mlp"], h, cfg.act, ctx,
+                              policy=policy, norm_scale=mlp_scale,
+                              eps=cfg.norm_eps)
+        x_t = x_t + m
+    return x_t, kv_cache
 
 
 # --------------------------------------------------------------------------
@@ -506,32 +522,35 @@ class TransformerLM:
 
     def _embed(self, params, tokens, batch):
         cfg = self.cfg
-        x = jnp.take(params["embed"], tokens, axis=0).astype(_dtype(cfg))
-        if cfg.family == "vlm" and "patch_embeds" in batch:
-            patches = batch["patch_embeds"].astype(_dtype(cfg))
-            x = jnp.concatenate([patches, x], axis=1)
-        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
-        return shard(x, ("act_batch", "act_seq_unsharded", "act_embed"),
-                     self.ctx)
+        with jax.named_scope("embed"):
+            x = jnp.take(params["embed"], tokens, axis=0
+                         ).astype(_dtype(cfg))
+            if cfg.family == "vlm" and "patch_embeds" in batch:
+                patches = batch["patch_embeds"].astype(_dtype(cfg))
+                x = jnp.concatenate([patches, x], axis=1)
+            x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+            return shard(x, ("act_batch", "act_seq_unsharded", "act_embed"),
+                         self.ctx)
 
     def _head(self, params, x):
         cfg = self.cfg
         w = params.get("lm_head")
-        if w is None:
-            w = params["embed"].T
-        if cfg.norm == "rmsnorm":
-            # the final norm→lm_head pair: fused (policy-gated) or the
-            # historical norm-then-einsum, decided in one place
-            logits = common.rmsnorm_matmul(
-                x, params["final_norm"]["scale"], w, cfg.norm_eps,
-                policy=self.policy)
-        else:
-            x = common.apply_norm(x, params["final_norm"], cfg.norm,
-                                  cfg.norm_eps, policy=self.policy)
-            logits = jnp.einsum("bsd,dv->bsv", x, w.astype(x.dtype))
-        return shard(logits.astype(jnp.float32),
-                     ("act_batch", "act_seq_unsharded", "act_vocab"),
-                     self.ctx)
+        with jax.named_scope("head"):
+            if w is None:
+                w = params["embed"].T
+            if cfg.norm == "rmsnorm":
+                # the final norm→lm_head pair: fused (policy-gated) or the
+                # historical norm-then-einsum, decided in one place
+                logits = common.rmsnorm_matmul(
+                    x, params["final_norm"]["scale"], w, cfg.norm_eps,
+                    policy=self.policy)
+            else:
+                x = common.apply_norm(x, params["final_norm"], cfg.norm,
+                                      cfg.norm_eps, policy=self.policy)
+                logits = jnp.einsum("bsd,dv->bsv", x, w.astype(x.dtype))
+            return shard(logits.astype(jnp.float32),
+                         ("act_batch", "act_seq_unsharded", "act_vocab"),
+                         self.ctx)
 
     # ---- layer stack ----
 
@@ -674,9 +693,10 @@ class TransformerLM:
         paged = "block_tables" in cache
         tables = cache["block_tables"] if paged else None
         pos = cache["pos"]
-        x = jnp.take(params["embed"], tokens[:, None], axis=0
-                     ).astype(_dtype(cfg))
-        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+        with jax.named_scope("embed"):
+            x = jnp.take(params["embed"], tokens[:, None], axis=0
+                         ).astype(_dtype(cfg))
+            x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
         # the decode shape of the flash→wo fusion (the cache attention +
         # output projection in one kernel, per-slot pos frontiers): on
         # the Pallas execution path whenever the policy fuses — wo is a

@@ -52,6 +52,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.serve.spans import (ADMIT, ADMIT_FIRST_TOKENS, ADMIT_PREFILL,
+                               ADMIT_REAP, ADMIT_SCATTER, STEP, SYNC,
+                               SYNC_APPLY, SYNC_FETCH, span)
+
 #: max ticks between harvest syncs once admissions have drained: bounds
 #: how much masked decode work a fully-EOS'd batch can waste
 _SYNC_STRIDE = 64
@@ -242,6 +246,11 @@ class BatchedEngine:
         donate = (2, 3, 4) if jax.default_backend() in ("tpu", "gpu") \
             else ()
         self._tick = jax.jit(self._tick_impl, donate_argnums=donate)
+        # admission's page write, one program per prompt length, with the
+        # pools donated: written in place, where an eager scatter makes a
+        # whole new pool (a pool-sized allocation every admission)
+        self._write_pages = jax.jit(self._write_pages_impl,
+                                    donate_argnums=(0,) if donate else ())
 
     def page_footprint_bytes(self) -> int:
         """Device bytes one KV page costs across the layer stack: K + V
@@ -279,9 +288,14 @@ class BatchedEngine:
         there are free slots — and, under paging, free *pages* — then
         fetch all first tokens in ONE host transfer.  Returns how many
         requests were admitted."""
-        self.sync()                    # make slot liveness current
-        if self._paged:
-            self._reap_done_pages()    # page budget current before admitting
+        with span(ADMIT):
+            return self._admit(reqs)
+
+    def _admit(self, reqs: List[Request]) -> int:
+        with span(ADMIT_REAP):
+            self.sync()                # make slot liveness current
+            if self._paged:
+                self._reap_done_pages()  # page budget current first
         staged = []                    # (req, slot, first_token_device)
         consumed = 0                   # prefix of reqs taken (staged+rejected)
         for req in reqs:
@@ -303,33 +317,41 @@ class BatchedEngine:
             # reap the finished occupant (exactly the slot we claim)
             req.slot = slot
             self.slots[slot] = req
-            toks = jnp.asarray(req.prompt, jnp.int32)[None, :]
-            logits, cache1 = self._prefill_one(self.params, toks)
+            with span(ADMIT_PREFILL, rid=req.rid):
+                toks = jnp.asarray(req.prompt, jnp.int32)[None, :]
+                logits, cache1 = self._prefill_one(self.params, toks)
+                first = jnp.argmax(logits[0]).astype(jnp.int32)
             if self._paged:
-                self._write_slot_paged(slot, cache1, len(req.prompt),
-                                       *plan)
+                page_ids, n_shared = plan
+                with span(ADMIT_SCATTER, rid=req.rid,
+                          fresh_pages=len(page_ids) - n_shared,
+                          shared_pages=n_shared):
+                    self._write_slot_paged(slot, cache1, len(req.prompt),
+                                           page_ids, n_shared)
             else:
-                self._write_slot(slot, cache1, len(req.prompt))
-            staged.append((req, slot,
-                           jnp.argmax(logits[0]).astype(jnp.int32)))
+                with span(ADMIT_SCATTER, rid=req.rid):
+                    self._write_slot(slot, cache1, len(req.prompt))
+            staged.append((req, slot, first))
             consumed += 1
         if not staged:
             return consumed
-        idx = jnp.asarray([s for _, s, _ in staged], jnp.int32)
-        firsts_dev = jnp.stack([t for _, _, t in staged])
-        budgets = jnp.asarray(
-            [max(r.max_new_tokens - 1, 0) for r, _, _ in staged], jnp.int32)
-        firsts = np.asarray(firsts_dev)          # the one admission sync
-        alive = []
-        for (req, _, _), tok in zip(staged, firsts):
-            tok = int(tok)
-            req.generated.append(tok)
-            req.done = (tok == self.cfg.eos_id
-                        or len(req.generated) >= req.max_new_tokens)
-            alive.append(not req.done)
-        self.last_tokens = self.last_tokens.at[idx].set(firsts_dev)
-        self.live = self.live.at[idx].set(jnp.asarray(alive))
-        self.remaining = self.remaining.at[idx].set(budgets)
+        with span(ADMIT_FIRST_TOKENS):
+            idx = jnp.asarray([s for _, s, _ in staged], jnp.int32)
+            firsts_dev = jnp.stack([t for _, _, t in staged])
+            budgets = jnp.asarray(
+                [max(r.max_new_tokens - 1, 0) for r, _, _ in staged],
+                jnp.int32)
+            firsts = np.asarray(firsts_dev)      # the one admission sync
+            alive = []
+            for (req, _, _), tok in zip(staged, firsts):
+                tok = int(tok)
+                req.generated.append(tok)
+                req.done = (tok == self.cfg.eos_id
+                            or len(req.generated) >= req.max_new_tokens)
+                alive.append(not req.done)
+            self.last_tokens = self.last_tokens.at[idx].set(firsts_dev)
+            self.live = self.live.at[idx].set(jnp.asarray(alive))
+            self.remaining = self.remaining.at[idx].set(budgets)
         return consumed
 
     def _write_slot(self, slot: int, cache1, prompt_len: int):
@@ -433,29 +455,38 @@ class BatchedEngine:
         pos = self.cache["pos"].at[slot].set(prompt_len)
         new_cache = dict(self.cache, block_tables=tables, pos=pos)
         n_prompt_pages = -(-prompt_len // ps)
-        write_ids = page_ids[n_shared:n_prompt_pages]
-        if write_ids:
-            ids = jnp.asarray(write_ids, jnp.int32)
-            pad = n_prompt_pages * ps - prompt_len
-            pairs = [("k_pages", "k"), ("v_pages", "v")]
-            if "k_scale_pages" in new_cache:
-                # int8 pools: the prefill's quantized strips carry scale
-                # strips ([L,1,Hkv,plen,1]) that scatter through the same
-                # page ids into the parallel scale pools
-                pairs += [("k_scale_pages", "k_scale"),
-                          ("v_scale_pages", "v_scale")]
-            for pool_name, strip_name in pairs:
-                strip = cache1[strip_name][:, 0]        # [L,Hkv,plen,hd]
-                if pad:
-                    strip = jnp.pad(
-                        strip, ((0, 0), (0, 0), (0, pad), (0, 0)))
-                nl, hkv, _, hd = strip.shape
-                pages = strip.reshape(nl, hkv, n_prompt_pages, ps, hd
-                                      ).transpose(0, 2, 1, 3, 4)
-                pages = pages[:, n_shared:n_prompt_pages]
-                new_cache[pool_name] = new_cache[pool_name].at[:, ids].set(
-                    pages.astype(new_cache[pool_name].dtype))
+        if n_shared < n_prompt_pages:
+            # shared pages already hold these bytes: their ids go to the
+            # sentinel, whose writes drop
+            ids = np.asarray(page_ids[:n_prompt_pages], np.int32)
+            ids[:n_shared] = self.num_pages
+            names = [n for n in ("k_pages", "v_pages", "k_scale_pages",
+                                 "v_scale_pages") if n in new_cache]
+            strips = {"k_pages": "k", "v_pages": "v",
+                      "k_scale_pages": "k_scale", "v_scale_pages": "v_scale"}
+            new_cache.update(self._write_pages(
+                {n: new_cache[n] for n in names},
+                {n: cache1[strips[n]] for n in names}, jnp.asarray(ids)))
         self.cache = new_cache
+
+    def _write_pages_impl(self, pools, strips, ids):
+        """Write a batch-1 prefill's strips (``[L,1,Hkv,plen,x]``) page by
+        page into the pools (``[L,P,Hkv,page_size,x]``) at ``ids``, one id
+        per prompt page; an id of ``num_pages`` drops its page.  Int8
+        caches' scale strips go through the same ids into the scale
+        pools."""
+        ps = self.cfg.page_size
+        out = {}
+        for name, pool in pools.items():
+            strip = strips[name][:, 0]                  # [L,Hkv,plen,x]
+            nl, hkv, plen, x = strip.shape
+            n = ids.shape[0]
+            strip = jnp.pad(strip, ((0, 0), (0, 0), (0, n * ps - plen),
+                                    (0, 0)))
+            pages = strip.reshape(nl, hkv, n, ps, x).transpose(0, 2, 1, 3, 4)
+            out[name] = pool.at[:, ids].set(pages.astype(pool.dtype),
+                                            mode="drop")
+        return out
 
     # ---- ticking ----
 
@@ -490,8 +521,9 @@ class BatchedEngine:
 
         Emitted tokens land in the device-side history; call :meth:`sync`
         (or :meth:`run`, which does) to drain them into the requests."""
-        out = self._tick(self.params, self.last_tokens, self.live,
-                         self.remaining, self.cache)
+        with span(STEP):
+            out = self._tick(self.params, self.last_tokens, self.live,
+                             self.remaining, self.cache)
         if self._paged:
             nxt, self.live, self.remaining, self.cache, stats = out
             self._stats_history.append(stats)
@@ -500,6 +532,16 @@ class BatchedEngine:
         self.last_tokens = nxt
         self._history.append(nxt)
         self.tick_count += 1
+
+    def tick_hlo_text(self) -> str:
+        """The optimized HLO text of the tick program as compiled for the
+        engine's current arguments (the compile cache serves it).  Its
+        instruction names are the operation names of a device trace, and
+        each instruction's ``metadata={op_name=...}`` carries the model's
+        ``jax.named_scope`` path."""
+        return self._tick.lower(self.params, self.last_tokens, self.live,
+                                self.remaining, self.cache
+                                ).compile().as_text()
 
     def _pending_harvest(self) -> dict:
         """The device half of :meth:`sync`: stack the token history (and
@@ -522,40 +564,45 @@ class BatchedEngine:
     def _apply_harvest(self, harvest: dict) -> None:
         """The host half of :meth:`sync`: replay a fetched harvest into
         the Request objects and the tick_stats rows."""
-        hist = harvest.get("hist")
-        if hist is not None:
-            for t in range(hist.shape[0]):
-                for slot, req in enumerate(self.slots):
-                    if req is None or req.done:
-                        continue
-                    tok = int(hist[t, slot])
-                    req.generated.append(tok)
-                    if tok == self.cfg.eos_id or \
-                            len(req.generated) >= req.max_new_tokens:
-                        req.done = True
-        rows = harvest.get("stats")
-        if rows is not None:
-            base = int(harvest["stats_base"])
-            for i in range(rows.shape[0]):
-                # device columns are per-tick; the pool columns are the
-                # host allocator's view at harvest time (admission-grain)
-                self.tick_stats.append({
-                    "tick": base + i,
-                    "live_slots": int(rows[i, 0]),
-                    "frontier_pages": int(rows[i, 1]),
-                    "pool_occupied_pages": self.pool.occupied_pages,
-                    "pool_utilization":
-                        self.pool.occupied_pages / max(self.num_pages, 1),
-                    "shared_prefix_hits": self.pool.shared_hits,
-                })
+        with span(SYNC_APPLY):
+            hist = harvest.get("hist")
+            if hist is not None:
+                for t in range(hist.shape[0]):
+                    for slot, req in enumerate(self.slots):
+                        if req is None or req.done:
+                            continue
+                        tok = int(hist[t, slot])
+                        req.generated.append(tok)
+                        if tok == self.cfg.eos_id or \
+                                len(req.generated) >= req.max_new_tokens:
+                            req.done = True
+            rows = harvest.get("stats")
+            if rows is not None:
+                base = int(harvest["stats_base"])
+                for i in range(rows.shape[0]):
+                    # device columns are per-tick; the pool columns are
+                    # the host allocator's view at harvest time
+                    # (admission-grain)
+                    self.tick_stats.append({
+                        "tick": base + i,
+                        "live_slots": int(rows[i, 0]),
+                        "frontier_pages": int(rows[i, 1]),
+                        "pool_occupied_pages": self.pool.occupied_pages,
+                        "pool_utilization":
+                            self.pool.occupied_pages / max(self.num_pages, 1),
+                        "shared_prefix_hits": self.pool.shared_hits,
+                    })
 
     def sync(self) -> None:
         """Drain the device-side token history into the Request objects
         with a single stacked device->host transfer (the paged per-tick
         stats vectors ride in the same fetch)."""
-        pending = self._pending_harvest()
-        if pending:
-            self._apply_harvest(jax.device_get(pending))
+        with span(SYNC):
+            pending = self._pending_harvest()
+            if pending:
+                with span(SYNC_FETCH):
+                    fetched = jax.device_get(pending)
+                self._apply_harvest(fetched)
 
     def run(self, requests: List[Request],
             max_ticks: int = 10_000) -> List[Request]:

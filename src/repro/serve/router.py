@@ -44,6 +44,7 @@ import jax
 
 from repro.serve.engine import (BatchedEngine, PagePool, Request,
                                 _SYNC_STRIDE)
+from repro.serve.spans import SYNC, SYNC_FETCH, span
 
 
 class CellRouter:
@@ -188,13 +189,15 @@ class CellRouter:
 
     def sync(self) -> None:
         """Harvest every cell in ONE stacked device->host fetch."""
-        pendings = [c._pending_harvest() for c in self.cells]
-        if not any(pendings):
-            return
-        fetched = jax.device_get(pendings)       # the one transfer
-        for cell, harvest in zip(self.cells, fetched):
-            if harvest:
-                cell._apply_harvest(harvest)
+        with span(SYNC):
+            pendings = [c._pending_harvest() for c in self.cells]
+            if not any(pendings):
+                return
+            with span(SYNC_FETCH):
+                fetched = jax.device_get(pendings)   # the one transfer
+            for cell, harvest in zip(self.cells, fetched):
+                if harvest:
+                    cell._apply_harvest(harvest)
 
     # ---- the serve loop ----
 

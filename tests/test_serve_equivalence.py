@@ -327,6 +327,38 @@ class TestPagedEngine:
         assert plain.pool.shared_hits == 0
         assert solo[0].generated == r0.generated
 
+    def test_page_write_is_one_program_per_prompt_length(
+            self, model_and_params):
+        """Admission writes its fresh prompt pages with one compiled
+        program per prompt length, however many pages it shares, and
+        changes no other page."""
+        model, params, cfg = model_and_params
+        prompt = _prompts(cfg, 1)[0] * 4     # >= 10 tokens: 1 full page
+        eng = BatchedEngine(model, params, ServeConfig(
+            batch_slots=2, max_seq_len=CACHE_LEN, eos_id=-1,
+            page_size=self.PAGE))
+        assert eng.admit([Request(rid=0, prompt=list(prompt),
+                                  max_new_tokens=5)]) == 1
+        pools = {k: eng.cache[k] for k in ("k_pages", "v_pages")}
+        r1 = Request(rid=1, prompt=list(prompt), max_new_tokens=5)
+        assert eng.admit([r1]) == 1
+        assert eng._slot_pages[1][0] == eng._slot_pages[0][0]
+        fresh = eng._slot_pages[1][1:-(-len(prompt) // self.PAGE)]
+        untouched = [p for p in range(eng.num_pages) if p not in fresh]
+        for k, before in pools.items():
+            after = eng.cache[k]
+            assert bool(jnp.all(after[:, jnp.asarray(untouched)]
+                                == before[:, jnp.asarray(untouched)]))
+            assert not bool(jnp.all(after[:, jnp.asarray(fresh)]
+                                    == before[:, jnp.asarray(fresh)]))
+        assert eng._write_pages._cache_size() == 1
+        eng.run([])
+        short = Request(rid=2, prompt=list(prompt[:3]), max_new_tokens=3)
+        eng.run([short])
+        assert short.generated == sequential_decode(
+            model, params, short.prompt, 3, eos=-1)
+        assert eng._write_pages._cache_size() == 2
+
     def test_reap_frees_pages_and_slot_reuse_is_clean(
             self, model_and_params):
         """Pages release exactly at reap; a newcomer over a reaped slot
