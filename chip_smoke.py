@@ -193,6 +193,16 @@ def rel_diff(a, b) -> float:
     return float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
 
 
+def pages_for(model, cache):
+    """A paged cache with token-major pages, as the XLA engine writes
+    them, in ``model``'s page layout: the Pallas kernel's pages are
+    head-major, ``[L, P, Hkv, page_size, x]``."""
+    if model.token_major_pages:
+        return cache
+    return {name: a.swapaxes(2, 3) if name.endswith("pages") else a
+            for name, a in cache.items()}
+
+
 def compare_phase(models, params, s: Smoke, device):
     """Logits of both policies on the same prompts and the same cache."""
     import jax.numpy as jnp
@@ -223,11 +233,12 @@ def compare_phase(models, params, s: Smoke, device):
                                              1))])
     logits = {}
     for name, model in models.items():
+        cache = pages_for(model, ref.cache)
         exe, secs, kernels = compile_checked(
-            model.decode_step, params, ref.last_tokens, ref.cache,
+            model.decode_step, params, ref.last_tokens, cache,
             pallas=name == "auto")
         found.update(kernels)
-        logits[name] = exe(params, ref.last_tokens, ref.cache)[0]
+        logits[name] = exe(params, ref.last_tokens, cache)[0]
         log(f"[smoke] compare decode_step {name}: compile {secs:.3f}s "
             f"kernels {kernels}")
     d = rel_diff(logits["auto"], logits["xla"])

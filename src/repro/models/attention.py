@@ -180,17 +180,45 @@ def update_cache(cache, new, pos):
 
 # ---------------------------------------------------------------------------
 # Paged KV cache: fixed-size pages + per-slot block tables (vLLM-style).
-# Pages: [P, Hkv, page_size, D] (one pool per layer; the page index axis is
-# shared across layers).  Block tables: [B, max_pages] int32 — entry j is
-# the page holding tokens [j*page_size, (j+1)*page_size); entries past a
-# slot's reservation hold the sentinel value ``P`` (out of range), so the
-# scatter write drops and the gather clamp reads a page whose contents are
-# masked anyway.  The serve engine owns allocation/refcounts
-# (repro.serve.engine.PagePool); everything here is pure device math.
+# Pages are token-major, [P, page_size, Hkv, D]: a token's row for every kv
+# head is contiguous, so the one-token write is one scatter of [Hkv, D]
+# rows (one pool per layer; the page index axis is shared across layers).
+# Their int8 scales sit in [P, rows, lanes] pools (:func:`scale_page_shape`):
+# the same values in the same order as [P, page_size, Hkv, 1], folded so
+# that a page is whole (8, 128) tiles and the stored layout is the one the
+# scatter and the flat carry take, whatever the page count.
+# The Pallas paged kernel reads (page, head) tiles of head-major pages,
+# [P, Hkv, page_size, D] with [P, Hkv, page_size, 1] scales, and writes
+# them through ``token_major=False``.
+# Block tables: [B, max_pages] int32 — entry j is the page holding tokens
+# [j*page_size, (j+1)*page_size); entries past a slot's reservation hold
+# the sentinel value ``P`` (out of range), so the scatter write drops and
+# the gather clamp reads a page whose contents are masked anyway.  The
+# serve engine owns allocation/refcounts (repro.serve.engine.PagePool);
+# everything here is pure device math.
 # ---------------------------------------------------------------------------
 
 
-def update_paged_cache(pages, new, block_tables, pos):
+def scale_page_shape(page_size: int, num_kv_heads: int):
+    """A token-major scale page's ``(rows, lanes)``: its ``page_size·Hkv``
+    per-(token, head) scales in token-major order, in rows of 128 lanes
+    where a token's scales never straddle two rows (one (8, 128) tile at
+    page 128 and 8 kv heads), else in one row."""
+    n = page_size * num_kv_heads
+    lanes = 128 if n % 128 == 0 and 128 % num_kv_heads == 0 else n
+    return n // lanes, lanes
+
+
+def _table_rows(block_tables, pos, page_size: int):
+    """The (page, row) each slot's logical index ``pos`` [B] resolves to
+    through the table."""
+    page_of = jnp.take_along_axis(
+        block_tables, (pos // page_size)[:, None], axis=1)[:, 0]    # [B]
+    return page_of, pos % page_size
+
+
+def update_paged_cache(pages, new, block_tables, pos, *,
+                       token_major: bool = True):
     """Insert new [B,Hkv,1,D] at logical index pos [B] through the table.
 
     The write resolves to page ``block_tables[b, pos[b] // page_size]`` at
@@ -201,40 +229,60 @@ def update_paged_cache(pages, new, block_tables, pos):
     past the table end clamp (jax gather semantics) onto the slot's own
     last entry, which the engine guarantees is never a shared page.
     """
-    num_pages, hkv, page_size, d = pages.shape
-    page_of = jnp.take_along_axis(
-        block_tables, (pos // page_size)[:, None], axis=1)[:, 0]    # [B]
-    offset = pos % page_size
-    return pages.at[page_of, :, offset].set(
-        new[:, :, 0, :].astype(pages.dtype), mode="drop")
+    page_of, offset = _table_rows(block_tables, pos,
+                                  pages.shape[1 if token_major else 2])
+    rows = new[:, :, 0, :].astype(pages.dtype)                      # [B,Hkv,D]
+    if token_major:
+        return pages.at[page_of, offset].set(rows, mode="drop")
+    return pages.at[page_of, :, offset].set(rows, mode="drop")
 
 
 def gather_paged_kv(pages, block_tables):
-    """[P,Hkv,page_size,D] + [B,max_pages] -> a [B,Hkv,S,D] logical strip.
+    """[P,page_size,Hkv,D] + [B,max_pages] -> a [B,Hkv,S,D] logical strip.
 
     Sentinel/dead entries clamp to the last real page; whatever they read
     sits past every consumer's ``pos`` frontier and is masked.  This is
     the library-row materialization — the Pallas decode kernel gathers the
     same pages through its index map without ever building the strip.
     """
-    num_pages = pages.shape[0]
-    tbl = jnp.minimum(block_tables, num_pages - 1)
-    strip = pages[tbl]                     # [B, max_pages, Hkv, ps, D]
-    b, maxp, hkv, ps, d = strip.shape
-    return strip.transpose(0, 2, 1, 3, 4).reshape(b, hkv, maxp * ps, d)
+    tbl = jnp.minimum(block_tables, pages.shape[0] - 1)
+    strip = pages[tbl]                     # [B, max_pages, ps, Hkv, D]
+    b, maxp, ps, hkv, d = strip.shape
+    return strip.reshape(b, maxp * ps, hkv, d).transpose(0, 2, 1, 3)
+
+
+def gather_paged_scales(scale_pages, block_tables, num_kv_heads: int):
+    """[P,rows,lanes] scales + [B,max_pages] -> a [B,Hkv,S,1] strip
+    (the :func:`gather_paged_kv` of an int8 cache's scale pool)."""
+    tbl = jnp.minimum(block_tables, scale_pages.shape[0] - 1)
+    strip = scale_pages[tbl]               # [B, max_pages, rows, lanes]
+    b = strip.shape[0]
+    return strip.reshape(b, -1, num_kv_heads).transpose(0, 2, 1)[..., None]
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_tables, pos, *,
+                           k_scale=None, v_scale=None,
                            scale: Optional[float] = None,
                            ctx: Optional[ShardCtx] = None):
     """Single-token attention against a paged cache (jnp reference).
 
-    q: [B,H,1,D]; pages: [P,Hkv,page_size,D]; block_tables: [B,max_pages];
+    q: [B,H,1,D]; pages: [P,page_size,Hkv,D]; block_tables: [B,max_pages];
     pos: [B].  Numerically identical to :func:`decode_attention` over the
     gathered strip — the masked-softmax math never sees page boundaries.
+    Int8 pages come with their ``[P,rows,lanes]`` scale pools: the
+    strips are gathered first and dequantized after, so a pool is never
+    dequantized whole.
     """
     k_cache = gather_paged_kv(k_pages, block_tables)
     v_cache = gather_paged_kv(v_pages, block_tables)
+    if k_scale is not None:
+        hkv = k_pages.shape[2]
+        k_cache = dequantize_kv(
+            k_cache, gather_paged_scales(k_scale, block_tables, hkv),
+            q.dtype)
+        v_cache = dequantize_kv(
+            v_cache, gather_paged_scales(v_scale, block_tables, hkv),
+            q.dtype)
     return decode_attention(q, k_cache, v_cache, pos, scale=scale, ctx=ctx)
 
 
@@ -270,17 +318,35 @@ def update_cache_int8(cache_q, cache_scale, new, pos):
     return cache_q, cache_scale
 
 
-def update_paged_cache_int8(pages, scale_pages, new, block_tables, pos):
+def update_paged_cache_int8(pages, scale_pages, new, block_tables, pos, *,
+                            token_major: bool = True):
     """Quantized paged write (ISSUE 7): the int8 composition of
     :func:`update_paged_cache`.
 
-    ``pages``: int8 ``[P,Hkv,page_size,D]``; ``scale_pages``: f32
-    ``[P,Hkv,page_size,1]`` — per-token scales in a pool of the *same*
-    page geometry, so both writes resolve through the same table entry
-    and the same sentinel/drop semantics (the value row and its scale can
-    never land on different pages).  ``new`` arrives bf16/f32 and is
-    quantized per-(token, head) here, at write time."""
+    ``pages``: int8 ``[P,page_size,Hkv,D]`` with f32 ``scale_pages``
+    ``[P,rows,lanes]`` (:func:`scale_page_shape`), or head-major
+    ``[P,Hkv,page_size,D]`` with ``[P,Hkv,page_size,1]`` scales under
+    ``token_major=False`` — per-token scales in pools of the *same*
+    pages, so both writes resolve through the same table entry and the
+    same sentinel/drop semantics (the value row and its scale can never
+    land on different pages).  ``new`` arrives bf16/f32 and is quantized
+    per-(token, head) here, at write time."""
     q_new, s_new = quantize_kv(new)
-    pages = update_paged_cache(pages, q_new, block_tables, pos)
-    scale_pages = update_paged_cache(scale_pages, s_new, block_tables, pos)
+    pages = update_paged_cache(pages, q_new, block_tables, pos,
+                               token_major=token_major)
+    if not token_major:
+        return pages, update_paged_cache(scale_pages, s_new, block_tables,
+                                         pos, token_major=False)
+    hkv, lanes = s_new.shape[1], scale_pages.shape[2]
+    page_of, offset = _table_rows(block_tables, pos, pages.shape[1])
+    # a token's Hkv scales are one window of one row: written in place,
+    # where a scatter of single scales has the pool staged and copied back
+    first = offset * hkv
+    at = jnp.stack([page_of, first // lanes, first % lanes], axis=1)
+    dims = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=(1,), inserted_window_dims=(0, 1),
+        scatter_dims_to_operand_dims=(0, 1, 2))
+    scale_pages = jax.lax.scatter(
+        scale_pages, at, s_new[:, :, 0, 0], dims,
+        mode=jax.lax.GatherScatterMode.FILL_OR_DROP)
     return pages, scale_pages

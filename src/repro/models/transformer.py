@@ -20,8 +20,9 @@ from repro.core.registry import ExecutionPolicy
 from repro.models import common, mlp
 from repro.models.attention import (chunked_attention, decode_attention,
                                     dequantize_kv, paged_decode_attention,
-                                    quantize_kv, update_cache,
-                                    update_cache_int8, update_paged_cache,
+                                    quantize_kv, scale_page_shape,
+                                    update_cache, update_cache_int8,
+                                    update_paged_cache,
                                     update_paged_cache_int8)
 from repro.models.config import (LEGACY_LAYOUT, ModelConfig, ParallelConfig,
                                  ParamLayout)
@@ -248,33 +249,38 @@ def attn_decode(params, x_t, cfg: ModelConfig, kv_cache, pos, ctx,
     output round trip per layer per tick.
 
     ``block_tables`` switches the cache to its *paged* form: kv_cache is
-    (k_pages, v_pages) ``[P, Hkv, page_size, hd]`` pools and the table
-    maps each slot's logical kv blocks to pool pages.  The one-token
-    write scatters through the table (sentinel entries drop), and the
-    fused path hands the table to the paged decode shape of
-    ``flash_attention_matmul`` so the kernel only visits live pages."""
+    (k_pages, v_pages) pools and the table maps each slot's logical kv
+    blocks to pool pages.  The one-token write scatters through the
+    table (sentinel entries drop).  The pools are token-major
+    ``[P, page_size, Hkv, hd]``, and attention runs over the gathered
+    strips; with ``fuse_wo`` they are the head-major
+    ``[P, Hkv, page_size, hd]`` pools the paged decode shape of
+    ``flash_attention_matmul`` reads, and the kernel gets the table so
+    it only visits live pages (``TransformerLM.token_major_pages``)."""
     b = x_t.shape[0]
     positions = pos[:, None]                       # [B,1]
     q, k_new, v_new = _project_qkv(params, x_t, cfg, positions, ctx,
                                    policy=policy, norm_scale=norm_scale)
     if block_tables is not None:
+        token_major = not fuse_wo
         with jax.named_scope("kv_write"):
             if int8:
                 # int8 paged cache: quantize-on-write through the same
-                # table scatter, per-page scales riding parallel
-                # [P,Hkv,ps,1] pools.
+                # table scatter, per-token scales riding parallel pools.
                 k_pages, k_sc, v_pages, v_sc = kv_cache
                 k_pages, k_sc = update_paged_cache_int8(
-                    k_pages, k_sc, k_new, block_tables, pos)
+                    k_pages, k_sc, k_new, block_tables, pos,
+                    token_major=token_major)
                 v_pages, v_sc = update_paged_cache_int8(
-                    v_pages, v_sc, v_new, block_tables, pos)
+                    v_pages, v_sc, v_new, block_tables, pos,
+                    token_major=token_major)
                 new_cache = (k_pages, k_sc, v_pages, v_sc)
             else:
                 k_pages, v_pages = kv_cache
                 k_pages = update_paged_cache(k_pages, k_new, block_tables,
-                                             pos)
+                                             pos, token_major=token_major)
                 v_pages = update_paged_cache(v_pages, v_new, block_tables,
-                                             pos)
+                                             pos, token_major=token_major)
                 k_sc = v_sc = None
                 new_cache = (k_pages, v_pages)
         if fuse_wo:
@@ -288,14 +294,11 @@ def attn_decode(params, x_t, cfg: ModelConfig, kv_cache, pos, ctx,
                     v_scale=v_sc)
             return out, new_cache
         with jax.named_scope("kv_read"):
-            if int8:
-                # unfused reference path: dequantize the gathered-from
-                # pools up front (the fused kernel instead dequantizes per
-                # page, in VMEM, only for live table entries)
-                k_pages = dequantize_kv(k_pages, k_sc, x_t.dtype)
-                v_pages = dequantize_kv(v_pages, v_sc, x_t.dtype)
+            # int8 strips dequantize after the gather (the fused kernel
+            # dequantizes per page, in VMEM, only for live table entries)
             o = paged_decode_attention(q, k_pages, v_pages, block_tables,
-                                       pos, ctx=ctx)
+                                       pos, k_scale=k_sc, v_scale=v_sc,
+                                       ctx=ctx)
         o = o.transpose(0, 2, 1, 3).reshape(b, 1, -1)
         out = jnp.einsum("bsh,hd->bsd", o, _wo_weight(params, x_t.dtype))
         return out, new_cache
@@ -475,6 +478,18 @@ class TransformerLM:
         # policy; only *this* model's init_params/param_specs emit the
         # planned layout.
         self.param_layout = ParamLayout.plan(cfg, self.policy)
+        # the decode shape of the flash→wo fusion (the cache attention +
+        # output projection in one kernel, per-slot pos frontiers): on
+        # the Pallas execution path whenever the policy fuses — wo is a
+        # single matrix, so unlike qkv/wig it needs no layout plan
+        self.fuse_wo = (par.use_pallas_attn and self.policy.fuses()
+                        and cfg.num_heads > 0)
+        # the page layout follows (resolved ONCE, with the policy): that
+        # kernel reads (page, head) tiles of head-major pages; every other
+        # paged tick writes a token's all-head row in one scatter and
+        # carries the pools whole through the layer scan, which takes
+        # token-major pages to stay free of pool-sized copies
+        self.token_major_pages = not self.fuse_wo
         self.aux_weight = 0.01 if cfg.moe is not None else 0.0
 
     def with_policy(self, policy: ExecutionPolicy) -> "TransformerLM":
@@ -637,7 +652,10 @@ class TransformerLM:
         """The paged form of :meth:`init_cache`: fixed-size KV pages plus
         per-slot block tables (capacity by pages, not slots).
 
-        Pools are ``[L, P, Hkv, page_size, hd]`` — the page index axis is
+        Pools are token-major ``[L, P, page_size, Hkv, hd]``, or
+        head-major ``[L, P, Hkv, page_size, hd]`` for the Pallas paged
+        kernel (:attr:`token_major_pages`; int8 scale pools follow, see
+        ``models/attention.py``) — the page index axis is
         shared across layers, so one table serves the whole scan.  Tables
         init to the sentinel ``num_pages`` (out of range): a write
         through a sentinel entry drops and a gather clamps onto a page
@@ -646,7 +664,10 @@ class TransformerLM:
         ``repro.serve.engine.PagePool``."""
         cfg = self.cfg
         hkv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
-        shape = (cfg.num_layers, num_pages, hkv, page_size, hd)
+        if self.token_major_pages:
+            shape = (cfg.num_layers, num_pages, page_size, hkv, hd)
+        else:
+            shape = (cfg.num_layers, num_pages, hkv, page_size, hd)
         tables = jnp.full((batch_size, max_pages_per_slot), num_pages,
                           jnp.int32)
         pos = jnp.zeros((batch_size,), jnp.int32)
@@ -654,8 +675,12 @@ class TransformerLM:
             # int8 pools + per-(token,head) f32 scale pools riding the
             # same page index axis — a page costs hd + 4 bytes per token
             # per head instead of 4*hd, which is where the PagePool
-            # capacity multiplier comes from (serve/engine.py).
-            sshape = shape[:-1] + (1,)
+            # capacity multiplier comes from (serve/engine.py)
+            if self.token_major_pages:
+                sshape = (cfg.num_layers, num_pages) + scale_page_shape(
+                    page_size, hkv)
+            else:
+                sshape = shape[:-1] + (1,)
             return {
                 "k_pages": jnp.zeros(shape, jnp.int8),
                 "k_scale_pages": jnp.full(sshape, 1e-8, jnp.float32),
@@ -685,8 +710,10 @@ class TransformerLM:
         """tokens: [B] int32 -> (logits [B,V], new cache).
 
         A cache carrying ``block_tables`` routes through the paged decode
-        path: per-layer (k_pages, v_pages) pools ride the scan while the
-        table and ``pos`` frontier broadcast — same one-program shape,
+        path: token-major pools ride the scan's carry whole
+        (:meth:`_scan_carried_pools`); the Pallas kernel's head-major
+        pools ride it per layer as ``xs``/``ys``, while the table and
+        ``pos`` frontier broadcast — same one-program shape,
         page-gathered attention."""
         cfg, ctx = self.cfg, self.ctx
         int8 = self.par.kv_cache_int8
@@ -697,18 +724,17 @@ class TransformerLM:
             x = jnp.take(params["embed"], tokens[:, None], axis=0
                          ).astype(_dtype(cfg))
             x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
-        # the decode shape of the flash→wo fusion (the cache attention +
-        # output projection in one kernel, per-slot pos frontiers): on
-        # the Pallas execution path whenever the policy fuses — wo is a
-        # single matrix, so unlike qkv/wig it needs no layout plan
-        fuse_wo = (self.par.use_pallas_attn and self.policy.fuses()
-                   and cfg.num_heads > 0)
+        if paged and self.token_major_pages:
+            x, pools = self._scan_carried_pools(params, x, cache)
+            logits = self._head(params, x)[:, 0]
+            return logits, dict(cache, **pools, pos=pos + 1)
 
         def body(h, layer):
             layer_params, kv = layer
             h, new_kv = block_decode(layer_params, h, cfg, kv, pos, ctx,
                                      int8=int8, policy=self.policy,
-                                     fuse_wo=fuse_wo, block_tables=tables)
+                                     fuse_wo=self.fuse_wo,
+                                     block_tables=tables)
             return h, new_kv
 
         if paged and int8:
@@ -737,3 +763,38 @@ class TransformerLM:
         else:
             new_cache = {"k": new_kvs[0], "v": new_kvs[1], "pos": pos + 1}
         return logits, new_cache
+
+    def _scan_carried_pools(self, params, x, cache):
+        """The layer scan of the token-major paged tick.
+
+        Handed to the scan as ``xs`` and stacked back as ``ys``, every
+        layer's pool is sliced out, relaid out and copied back whole each
+        tick.  Here the pools ride the carry instead, flattened to
+        ``[L·P, ...]`` (a bitcast), and layer ``l`` reaches its own pages
+        through the table offset by ``l·P``: the scatter writes in place
+        and only the slots' strips are gathered.  The sentinel maps to
+        ``L·P``, still out of range, so a reaped slot's writes drop and
+        its gathers clamp onto a page the ``pos`` mask hides.  Returns the
+        final residual and the pools at ``[L, P, ...]``.
+        """
+        cfg = self.cfg
+        tables, pos = cache["block_tables"], cache["pos"]
+        names = [n for n in ("k_pages", "k_scale_pages", "v_pages",
+                             "v_scale_pages") if n in cache]
+        nl, npages = cache["k_pages"].shape[:2]
+        flat = tuple(cache[n].reshape((nl * npages,) + cache[n].shape[2:])
+                     for n in names)
+
+        def body(carry, layer):
+            h, pools = carry
+            layer_params, l = layer
+            tbl = jnp.where(tables < npages, tables + l * npages,
+                            nl * npages)
+            h, pools = block_decode(layer_params, h, cfg, pools, pos,
+                                    self.ctx, int8=self.par.kv_cache_int8,
+                                    policy=self.policy, block_tables=tbl)
+            return (h, pools), None
+
+        (x, flat), _ = jax.lax.scan(body, (x, flat),
+                                    (params["blocks"], jnp.arange(nl)))
+        return x, {n: p.reshape(cache[n].shape) for n, p in zip(names, flat)}

@@ -276,8 +276,17 @@ class BatchedEngine:
         return None
 
     def _prefill_one_impl(self, params, tokens):
-        """Single-sequence prefill -> (last_logits, cache_for_batch1)."""
-        return self.model.prefill(params, {"tokens": tokens})
+        """Single-sequence prefill -> (last_logits, cache_for_batch1).
+
+        For token-major pages the strips leave the prefill's program in
+        page order, ``[L,1,plen,Hkv,x]``: relaid out in the page write
+        instead, they cost that program about a second more to compile,
+        for every prompt length."""
+        logits, cache1 = self.model.prefill(params, {"tokens": tokens})
+        if self._paged and self.model.token_major_pages:
+            cache1 = {name: a.swapaxes(2, 3) if a.ndim == 5 else a
+                      for name, a in cache1.items()}
+        return logits, cache1
 
     def add_request(self, req: Request) -> bool:
         """Claim a slot and prefill it.  False if engine is full."""
@@ -470,20 +479,30 @@ class BatchedEngine:
         self.cache = new_cache
 
     def _write_pages_impl(self, pools, strips, ids):
-        """Write a batch-1 prefill's strips (``[L,1,Hkv,plen,x]``) page by
-        page into the pools (``[L,P,Hkv,page_size,x]``) at ``ids``, one id
-        per prompt page; an id of ``num_pages`` drops its page.  Int8
+        """Write a batch-1 prefill's strips page by page into the pools at
+        ``ids``, one id per prompt page; an id of ``num_pages`` drops its
+        page.  Strips and pools are in the model's page layout: token-
+        major strips ``[L,1,plen,Hkv,x]`` into ``[L,P,page_size,Hkv,hd]``
+        pools (scales ``[L,P,rows,lanes]``), or head-major strips
+        ``[L,1,Hkv,plen,x]`` into ``[L,P,Hkv,page_size,x]`` pools.  Int8
         caches' scale strips go through the same ids into the scale
         pools."""
         ps = self.cfg.page_size
+        n = ids.shape[0]
+        token_major = self.model.token_major_pages
         out = {}
         for name, pool in pools.items():
-            strip = strips[name][:, 0]                  # [L,Hkv,plen,x]
-            nl, hkv, plen, x = strip.shape
-            n = ids.shape[0]
-            strip = jnp.pad(strip, ((0, 0), (0, 0), (0, n * ps - plen),
-                                    (0, 0)))
-            pages = strip.reshape(nl, hkv, n, ps, x).transpose(0, 2, 1, 3, 4)
+            strip = strips[name][:, 0]
+            seq = 1 if token_major else 2
+            pad = [(0, 0)] * strip.ndim
+            pad[seq] = (0, n * ps - strip.shape[seq])
+            strip = jnp.pad(strip, pad)
+            if token_major:
+                pages = strip.reshape((strip.shape[0], n) + pool.shape[2:])
+            else:
+                nl, hkv, _, x = strip.shape
+                pages = strip.reshape(nl, hkv, n, ps, x).transpose(
+                    0, 2, 1, 3, 4)
             out[name] = pool.at[:, ids].set(pages.astype(pool.dtype),
                                             mode="drop")
         return out

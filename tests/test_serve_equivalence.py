@@ -3,8 +3,11 @@ produce token-for-token what sequential single-request decoding produces,
 including across mid-stream admissions and slot reuse — plus regression
 tests pinning the host-sync-free tick (one compiled program, zero host
 transfers inside the tick loop)."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.models import build_model
@@ -30,11 +33,10 @@ def sequential_decode(model, params, prompt, max_new, eos):
     toks = jnp.asarray(prompt, jnp.int32)[None, :]
     logits, cache = model.prefill(params, {"tokens": toks})
     pad = CACHE_LEN - cache["k"].shape[3]
-    cache = {
-        "k": jnp.pad(cache["k"], ((0, 0),) * 3 + ((0, pad), (0, 0))),
-        "v": jnp.pad(cache["v"], ((0, 0),) * 3 + ((0, pad), (0, 0))),
-        "pos": cache["pos"],
-    }
+    # k and v, and an int8 cache's scales, padded along the sequence
+    cache = {name: a if name == "pos" else
+             jnp.pad(a, ((0, 0),) * 3 + ((0, pad), (0, 0)))
+             for name, a in cache.items()}
     out = [int(jnp.argmax(logits[0]))]
     while out[-1] != eos and len(out) < max_new:
         lg, cache = model.decode_step(
@@ -413,6 +415,45 @@ class TestPagedEngine:
         assert first["live_slots"] == 2
         assert 0 < first["frontier_pages"] <= eng.num_pages
         assert 0.0 < first["pool_utilization"] <= 1.0
+
+    @pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
+    def test_layer_offset_keeps_pages_apart(self, int8):
+        """The pools ride the tick's layer scan flattened to [L·P, ...],
+        each layer reaching its pages through the table offset by l·P.
+        With a reaped slot whose pos keeps advancing, no layer writes a
+        page that no request holds, and the live slot's tokens are the
+        sequential ones."""
+        _, cfg = tiny_model()
+        cfg = dataclasses.replace(cfg, num_layers=3)
+        model = build_model(cfg, ParallelConfig(remat="none",
+                                                kv_cache_int8=int8))
+        assert model.token_major_pages
+        params = model.init_params(KEY)
+        eng = BatchedEngine(model, params, ServeConfig(
+            batch_slots=2, max_seq_len=CACHE_LEN, eos_id=-1,
+            page_size=self.PAGE, num_pages=8))
+        short, long_ = _prompts(cfg, 2)
+        r0 = Request(rid=0, prompt=short[:3], max_new_tokens=2)
+        r1 = Request(rid=1, prompt=long_, max_new_tokens=14)
+        assert eng.admit([r0, r1]) == 2
+        eng.step()
+        assert eng.admit([]) == 0               # syncs, then reaps r0
+        assert r0.done and eng._slot_pages[0] == []
+        free = [p for p in range(eng.num_pages) if p not in eng.pool.refcount]
+        assert 0 in free                          # r0's page, now free
+        names = [n for n in eng.cache if n.endswith("pages")]
+        before = {n: np.asarray(eng.cache[n]) for n in names}
+        for _ in range(6):
+            eng.step()
+        eng.sync()
+        for n in names:
+            after = np.asarray(eng.cache[n])
+            assert after.shape[0] == 3
+            np.testing.assert_array_equal(after[:, free],
+                                          before[n][:, free], err_msg=n)
+        eng.run([])
+        assert r1.generated == sequential_decode(model, params, r1.prompt,
+                                                 14, eos=-1)
 
     def test_paged_hbm_cost_scales_with_occupied_pages(
             self, model_and_params):

@@ -9,7 +9,8 @@ is compiled here with ``interpret=False`` at the operand shapes granite-8b
 hands it (d_model 4096, 32/8 heads, head_dim 128, d_ff 14336, vocab
 49152, bf16; 2048-token prefill rows, 16 decode slots, 128-token pages),
 together with the native rows, and so are a 2-layer full-width prefill
-and paged decode step.
+and paged decode step.  The default policy's paged tick and page write
+are compiled at a served pool's size and searched for pool-sized copies.
 
 The topology is described inside a module fixture, never at import: the
 TPU library may be loaded by one process at a time, and pytest-xdist
@@ -18,6 +19,7 @@ around these compiles (a cached entry for a described chip cannot be
 read back without one).
 """
 import dataclasses
+import math
 import os
 import re
 
@@ -32,6 +34,7 @@ from repro.core.registry import REGISTRY, ExecutionPolicy
 from repro.kernels import ops
 from repro.models import build_model
 from repro.models.config import ParallelConfig
+from repro.serve import BatchedEngine, ServeConfig
 
 D, F, V = GRANITE.d_model, GRANITE.d_ff, GRANITE.vocab_size
 H, HKV, HD = GRANITE.num_heads, GRANITE.num_kv_heads, GRANITE.head_dim
@@ -182,3 +185,115 @@ def test_full_width_paged_decode_compiles_with_kernels(chip, model_2l):
     assert {"uisa_rmsnorm_matmul_abstract_shuffle",
             "uisa_rmsnorm_swiglu_abstract_shuffle",
             "uisa_paged_attention_matmul_abstract_shuffle"} <= found, found
+
+
+#: a served pool: 32 slots over 400 pages, 12 pages a slot
+TICK_SLOTS, TICK_PAGES, TICK_MAXP = 32, 400, 12
+#: what moves a pool without computing: by opcode, or in a fusion's name
+#: (which XLA takes from the fused root); a dynamic-update-slice moves
+#: its update, in place, and is judged by that
+_COPYING = ("dynamic-slice", "copy", "transpose")
+_INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* "
+                    r"([\w\-]+)\(%?([\w.\-]+)?(?:, %?([\w.\-]+))?")
+#: pool-sized copies in the Pallas path's compiled tick before the XLA
+#: tick carried its pools whole (each layer's pool sliced in, relaid out
+#: and stacked back, and the stack copied onto the donated input)
+PALLAS_POOL_COPIES = {"bf16": 20, "int8": 32}
+
+
+def pool_copies(text, pools):
+    """Names of the instructions in compiled HLO ``text`` that slice,
+    stack, copy or transpose an array the size of one of ``pools``
+    (``[L, P, ...]`` shapes): the whole pool, the flat ``[L·P, ...]``
+    one, or one layer's."""
+    sizes = set()
+    for shape in pools:
+        sizes |= {math.prod(shape), math.prod(shape[1:])}
+    instrs = [m.groups() for m in map(_INSTR.match, text.splitlines()) if m]
+    size = {name: math.prod(int(d) for d in dims.split(",") if d)
+            for name, dims, *_ in instrs}
+    found = []
+    for name, dims, op, _, update in instrs:
+        if op == "dynamic-update-slice":
+            moved = size.get(update, 0)
+        elif any(w in (name if op == "fusion" else op).replace("_", "-")
+                 for w in _COPYING):
+            moved = size[name]
+        else:
+            continue
+        if moved in sizes:
+            found.append(name)
+    return found
+
+
+def paged_model(chip, pallas: bool, int8: bool):
+    """granite-8b at full width, 2 layers, and the shapes of its paged
+    tick's operands on the chip: (model, params, tokens, cache)."""
+    cfg = dataclasses.replace(GRANITE, num_layers=2)
+    extra = dict(isa_mode="auto", use_pallas_attn=True) if pallas else {}
+    model = build_model(cfg, ParallelConfig(remat="none",
+                                            kv_cache_int8=int8, **extra))
+    model = model.with_policy(dataclasses.replace(model.policy,
+                                                  interpret=False))
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+            tree)
+    params = on_chip(jax.eval_shape(model.init_params,
+                                    jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(lambda: model.init_paged_cache(
+        TICK_SLOTS, TICK_PAGES, PAGE, TICK_MAXP)))
+    return model, params, shape(chip, TICK_SLOTS, dtype=jnp.int32), cache
+
+
+def pool_shapes(cache):
+    return [a.shape for k, a in cache.items() if k.endswith("pages")]
+
+
+def compiled_tick(model, params, tokens, cache):
+    return jax.jit(model.decode_step, donate_argnums=2).lower(
+        params, tokens, cache).compile().as_text()
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_default_paged_tick_copies_no_pool(chip, int8):
+    """The default policy's tick carries token-major pools whole through
+    the layer scan: what is left of a pool's traffic is the scatter of
+    the new rows and the gather of the slots' strips."""
+    model, params, tokens, cache = paged_model(chip, False, int8)
+    assert model.token_major_pages
+    text = compiled_tick(model, params, tokens, cache)
+    assert "scatter" in text
+    assert pool_copies(text, pool_shapes(cache)) == []
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_default_page_write_copies_no_pool(chip, int8):
+    """Admission's page write, pools donated, writes the prompt strip's
+    token-major pages in place."""
+    model, _, _, cache = paged_model(chip, False, int8)
+    engine = BatchedEngine(model, None, ServeConfig(
+        batch_slots=1, max_seq_len=PAGE, eos_id=-1, page_size=PAGE,
+        num_pages=1))
+    pools = {k: a for k, a in cache.items() if k.endswith("pages")}
+    strips = {k: shape(chip, a.shape[0], 1, PREFILL, HKV,
+                       1 if "scale" in k else HD, dtype=a.dtype)
+              for k, a in pools.items()}
+    ids = shape(chip, PREFILL // PAGE, dtype=jnp.int32)
+    text = jax.jit(engine._write_pages_impl, donate_argnums=0).lower(
+        pools, strips, ids).compile().as_text()
+    assert pool_copies(text, pool_shapes(cache)) == []
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_pallas_paged_tick_copies_no_more_pools(chip, int8):
+    """The Pallas paged kernel keeps head-major pages and the per-layer
+    scan; its tick moves no more pools than it did before."""
+    model, params, tokens, cache = paged_model(chip, True, int8)
+    assert not model.token_major_pages
+    text = compiled_tick(model, params, tokens, cache)
+    assert "tpu_custom_call" in text
+    found = pool_copies(text, pool_shapes(cache))
+    assert len(found) <= PALLAS_POOL_COPIES["int8" if int8 else "bf16"], \
+        found
