@@ -1,4 +1,4 @@
-"""Assigned-architecture registry (10 archs) + dry-run input specs.
+"""Assigned-architecture registry (11 archs) + dry-run input specs.
 
 Every module in this package defines:
   CONFIG   — the exact assigned full-size ModelConfig
@@ -31,7 +31,11 @@ ARCHS = (
     "zamba2-1.2b",
     "mamba2-2.7b",
     "llava-next-mistral-7b",
+    "granite-4.0-h-micro",
 )
+
+
+_REDUCED_SUFFIX = "-reduced"
 
 
 def _module(name: str):
@@ -40,6 +44,10 @@ def _module(name: str):
 
 
 def get_config(name: str) -> ModelConfig:
+    """The arch's full config, or its REDUCED one by that config's name
+    (``<arch>-reduced``)."""
+    if name.endswith(_REDUCED_SUFFIX):
+        return get_reduced(name[:-len(_REDUCED_SUFFIX)])
     return _module(name).CONFIG
 
 

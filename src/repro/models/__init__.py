@@ -1,7 +1,8 @@
 """Model registry: family string -> model class.
 
 Families (models/config.py): dense / moe / vlm -> TransformerLM,
-ssm -> MambaLM, hybrid -> HybridLM, encdec -> EncDecLM.  All expose the
+ssm -> MambaLM, hybrid -> HybridLM (GraniteHybridLM when the config lists
+its ``layer_types``), encdec -> EncDecLM.  All expose the
 same functional surface: init_params / param_specs / loss_fn / prefill /
 init_cache / cache_specs / decode_step.
 """
@@ -16,6 +17,7 @@ from repro.parallel.sharding import ShardCtx
 def build_model(cfg: ModelConfig, par: Optional[ParallelConfig] = None,
                 ctx: Optional[ShardCtx] = None):
     from repro.models.encdec import EncDecLM
+    from repro.models.granite_hybrid import GraniteHybridLM
     from repro.models.hybrid import HybridLM
     from repro.models.mamba_lm import MambaLM
     from repro.models.transformer import TransformerLM
@@ -26,6 +28,8 @@ def build_model(cfg: ModelConfig, par: Optional[ParallelConfig] = None,
         return TransformerLM(cfg, par, ctx)
     if family == "ssm":
         return MambaLM(cfg, par, ctx)
+    if family == "hybrid" and cfg.layer_types is not None:
+        return GraniteHybridLM(cfg, par, ctx)
     if family == "hybrid":
         return HybridLM(cfg, par, ctx)
     if family in ("encdec", "audio"):

@@ -233,12 +233,16 @@ def update_paged_cache(pages, new, block_tables, pos, *,
                                   pages.shape[1 if token_major else 2])
     rows = new[:, :, 0, :].astype(pages.dtype)                      # [B,Hkv,D]
     if token_major:
+        # a pool of [P, page_size, Hkv·D] rows takes them flattened
+        rows = rows.reshape(rows.shape[:1] + pages.shape[2:])
         return pages.at[page_of, offset].set(rows, mode="drop")
     return pages.at[page_of, :, offset].set(rows, mode="drop")
 
 
-def gather_paged_kv(pages, block_tables):
-    """[P,page_size,Hkv,D] + [B,max_pages] -> a [B,Hkv,S,D] logical strip.
+def gather_paged_kv(pages, block_tables, head_dim: Optional[int] = None):
+    """[P,page_size,Hkv,D] + [B,max_pages] -> a [B,Hkv,S,D] logical strip
+    (``[P,page_size,Hkv·D]`` pools, heads flattened into their rows, give
+    ``head_dim``).
 
     Sentinel/dead entries clamp to the last real page; whatever they read
     sits past every consumer's ``pos`` frontier and is masked.  This is
@@ -247,8 +251,9 @@ def gather_paged_kv(pages, block_tables):
     """
     tbl = jnp.minimum(block_tables, pages.shape[0] - 1)
     strip = pages[tbl]                     # [B, max_pages, ps, Hkv, D]
-    b, maxp, ps, hkv, d = strip.shape
-    return strip.reshape(b, maxp * ps, hkv, d).transpose(0, 2, 1, 3)
+    b, maxp, ps = strip.shape[:3]
+    d = head_dim or strip.shape[-1]
+    return strip.reshape(b, maxp * ps, -1, d).transpose(0, 2, 1, 3)
 
 
 def gather_paged_scales(scale_pages, block_tables, num_kv_heads: int):
@@ -266,15 +271,16 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, pos, *,
                            ctx: Optional[ShardCtx] = None):
     """Single-token attention against a paged cache (jnp reference).
 
-    q: [B,H,1,D]; pages: [P,page_size,Hkv,D]; block_tables: [B,max_pages];
-    pos: [B].  Numerically identical to :func:`decode_attention` over the
-    gathered strip — the masked-softmax math never sees page boundaries.
+    q: [B,H,1,D]; pages: [P,page_size,Hkv,D] (or ``[P,page_size,Hkv·D]``);
+    block_tables: [B,max_pages]; pos: [B].  Numerically identical to
+    :func:`decode_attention` over the gathered strip — the masked-softmax
+    math never sees page boundaries.
     Int8 pages come with their ``[P,rows,lanes]`` scale pools: the
     strips are gathered first and dequantized after, so a pool is never
     dequantized whole.
     """
-    k_cache = gather_paged_kv(k_pages, block_tables)
-    v_cache = gather_paged_kv(v_pages, block_tables)
+    k_cache = gather_paged_kv(k_pages, block_tables, q.shape[-1])
+    v_cache = gather_paged_kv(v_pages, block_tables, q.shape[-1])
     if k_scale is not None:
         hkv = k_pages.shape[2]
         k_cache = dequantize_kv(

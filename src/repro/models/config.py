@@ -74,6 +74,19 @@ class ModelConfig:
     encdec: Optional[EncDecConfig] = None
     vlm: Optional[VLMConfig] = None
     hybrid: Optional[HybridConfig] = None
+    # Granite-4.0-H style hybrid (family "hybrid" with this set): the kind
+    # of each layer, "mamba" or "attention", each layer followed by its
+    # own MLP; attention layers carry their own weights
+    layer_types: Optional[Tuple[str, ...]] = None
+    # muP multipliers (None / 1.0: the plain model).  The embedded tokens
+    # are scaled by embedding_multiplier (TransformerLM: sqrt(d_model)
+    # when None), each residual branch by residual_multiplier, the logits
+    # divided by logits_scaling, and attention scores by
+    # attention_multiplier (None: head_dim ** -0.5)
+    embedding_multiplier: Optional[float] = None
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attention_multiplier: Optional[float] = None
     max_seq_len: int = 131072
     dtype: str = "bfloat16"       # activations/weights compute dtype
     # sub-quadratic attention available? (long_500k eligibility)
@@ -84,6 +97,15 @@ class ModelConfig:
         if self.head_dim is not None:
             return self.head_dim
         return self.d_model // max(self.num_heads, 1)
+
+    @property
+    def attention_layers(self) -> int:
+        """How many layers hold attention (with a KV cache)."""
+        if self.layer_types is not None:
+            return self.layer_types.count("attention")
+        if self.family == "hybrid":
+            return self.num_layers // self.hybrid.attn_every
+        return self.num_layers if self.num_heads else 0
 
     def param_count(self) -> int:
         """Approximate parameter count N (used for 6·N·D MODEL_FLOPS)."""
@@ -117,6 +139,11 @@ class ModelConfig:
             per_ssm = (d * (2 * d_in + 2 * cfg.n_groups * cfg.state_dim + nh)
                        + conv_dim * cfg.conv_width + 3 * nh + d_in
                        + d_in * d)
+            if self.layer_types is not None:
+                n_attn = self.attention_layers
+                return (total + (self.num_layers - n_attn) * per_ssm
+                        + n_attn * per_layer_attn
+                        + self.num_layers * per_layer_mlp)
             shared_attn = per_layer_attn + per_layer_mlp
             return total + self.num_layers * per_ssm + shared_attn
         if self.moe is not None:

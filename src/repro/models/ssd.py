@@ -39,15 +39,24 @@ def conv_dim(cfg: SSMConfig, d_model: int) -> int:
     return d_inner + 2 * cfg.n_groups * cfg.state_dim
 
 
+#: the input projection's three parts, stored apart: at d_inner 4096,
+#: d_state 128 and 64 heads the whole would be 8512 columns, which the TPU
+#: lays out transposed and a decode step would relay out every call
+IN_PARTS = ("in_z", "in_xbc", "in_dt")
+
+
 def init_mamba_block(key, d_model: int, cfg: SSMConfig, dtype):
     d_inner = cfg.expand * d_model
     nh = d_inner // cfg.head_dim
     cdim = conv_dim(cfg, d_model)
     ks = jax.random.split(key, 6)
+    # x @ [z | x B C | dt], drawn whole and stored as its three parts
+    gn2 = 2 * cfg.n_groups * cfg.state_dim
+    in_proj = common.dense_init(ks[0], (d_model, 2 * d_inner + gn2 + nh),
+                                0, dtype)
     params = {
-        "in_proj": common.dense_init(
-            ks[0], (d_model, 2 * d_inner + 2 * cfg.n_groups * cfg.state_dim
-                    + nh), 0, dtype),
+        **dict(zip(IN_PARTS, jnp.split(
+            in_proj, [d_inner, 2 * d_inner + gn2], axis=-1))),
         "conv_w": (jax.random.normal(ks[1], (cfg.conv_width, cdim))
                    * (cfg.conv_width ** -0.5)).astype(dtype),
         "conv_b": jnp.zeros((cdim,), dtype),
@@ -60,7 +69,7 @@ def init_mamba_block(key, d_model: int, cfg: SSMConfig, dtype):
         "out_proj": common.dense_init(ks[5], (d_inner, d_model), 0, dtype),
     }
     specs = {
-        "in_proj": ("embed", "ssm_inner"),
+        **{k: ("embed", "ssm_inner") for k in IN_PARTS},
         "conv_w": ("conv_width", "ssm_inner"),
         "conv_b": ("ssm_inner",),
         "dt_bias": ("ssm_heads",),
@@ -129,12 +138,22 @@ def ssd_decode_step(state, x_t, dt_t, A, B_t, C_t):
     return kernel_ssd.ssd_decode_reference(state, x_t, dt_t, A, B_t, C_t)
 
 
-def _split_proj(z_xbc_dt, d_inner: int, gn2: int, nh: int):
-    z = z_xbc_dt[..., :d_inner]
-    xbc = z_xbc_dt[..., d_inner:2 * d_inner + gn2]
-    dt_raw = z_xbc_dt[..., 2 * d_inner + gn2:]
-    assert dt_raw.shape[-1] == nh
-    return z, xbc, dt_raw
+def gated_rmsnorm(y, z, weight, eps: float, n_groups: int, policy=None):
+    """Mamba-2's gated norm: ``y * silu(z)`` in float32, RMS-normalised
+    over each of the ``n_groups`` groups of ``d_inner / n_groups``
+    channels, times ``weight``.  Returns float32."""
+    h = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    if n_groups == 1:
+        return common.rmsnorm(h, weight, eps, policy=policy)
+    hg = h.reshape(h.shape[:-1] + (n_groups, h.shape[-1] // n_groups))
+    hg = hg * jax.lax.rsqrt(jnp.mean(hg * hg, axis=-1, keepdims=True) + eps)
+    return hg.reshape(h.shape) * weight.astype(jnp.float32)
+
+
+def _in_proj(params, x):
+    """``x @ [z | x B C | dt]``, one matmul per stored part."""
+    return tuple(jnp.einsum("...d,de->...e", x, params[k].astype(x.dtype))
+                 for k in IN_PARTS)
 
 
 def apply_mamba_block(params, x, cfg: SSMConfig, d_model: int,
@@ -147,9 +166,9 @@ def apply_mamba_block(params, x, cfg: SSMConfig, d_model: int,
     nh = d_inner // cfg.head_dim
     gn2 = 2 * cfg.n_groups * cfg.state_dim
 
-    proj = jnp.einsum("bld,de->ble", x, params["in_proj"].astype(x.dtype))
-    z, xbc, dt_raw = _split_proj(proj, d_inner, gn2, nh)
-    xbc = jax.nn.silu(_causal_conv(xbc, params["conv_w"], params["conv_b"])
+    z, xbc_pre, dt_raw = _in_proj(params, x)
+    xbc = jax.nn.silu(_causal_conv(xbc_pre, params["conv_w"],
+                                   params["conv_b"])
                       ).astype(x.dtype)                # silu in f32, one cast
     xs = xbc[..., :d_inner]
     B_mat = xbc[..., d_inner:d_inner + gn2 // 2].reshape(
@@ -180,12 +199,11 @@ def apply_mamba_block(params, x, cfg: SSMConfig, d_model: int,
     y = y + (params["D"].reshape(nh, 1)
              * xh.astype(jnp.float32)).astype(y.dtype)
     y = y.reshape(b, l, d_inner)
-    y = common.rmsnorm(y * jax.nn.silu(z), params["norm_scale"], eps,
-                       policy=policy)
+    y = gated_rmsnorm(y, z, params["norm_scale"], eps, cfg.n_groups,
+                      policy=policy).astype(x.dtype)
     out = jnp.einsum("ble,ed->bld", y, params["out_proj"].astype(x.dtype))
     if return_state:
-        conv_tail = _conv_tail(xbc_pre_conv=proj[..., d_inner:2 * d_inner + gn2],
-                               width=cfg.conv_width)
+        conv_tail = _conv_tail(xbc_pre_conv=xbc_pre, width=cfg.conv_width)
         return out, (state, conv_tail)
     return out
 
@@ -212,8 +230,7 @@ def mamba_decode_step(params, x_t, cfg: SSMConfig, d_model: int,
     nh = d_inner // cfg.head_dim
     gn2 = 2 * cfg.n_groups * cfg.state_dim
 
-    proj = jnp.einsum("bd,de->be", x_t, params["in_proj"].astype(x_t.dtype))
-    z, xbc_new, dt_raw = _split_proj(proj, d_inner, gn2, nh)
+    z, xbc_new, dt_raw = _in_proj(params, x_t)
 
     window = jnp.concatenate([conv_buf, xbc_new[:, None, :]], axis=1)
     w = params["conv_w"].astype(jnp.float32)          # [W,C]
@@ -246,7 +263,7 @@ def mamba_decode_step(params, x_t, cfg: SSMConfig, d_model: int,
     y = y + (params["D"].reshape(nh, 1)
              * xh.astype(jnp.float32)).astype(y.dtype)
     y = y.reshape(b, d_inner)
-    y = common.rmsnorm(y * jax.nn.silu(z), params["norm_scale"], eps,
-                       policy=policy)
+    y = gated_rmsnorm(y, z, params["norm_scale"], eps, cfg.n_groups,
+                      policy=policy).astype(x_t.dtype)
     out = jnp.einsum("be,ed->bd", y, params["out_proj"].astype(x_t.dtype))
     return out, state, new_conv_buf

@@ -221,7 +221,8 @@ def attn_seq(params, x, cfg: ModelConfig, par: ParallelConfig,
         o = chunked_attention(
             q, k_rep, v_rep, causal=causal, kv_offset=0,
             chunk_q=par.attn_chunk_q, chunk_kv=par.attn_chunk_kv,
-            exact_causal=par.causal_folding, ctx=ctx)
+            exact_causal=par.causal_folding, scale=cfg.attention_multiplier,
+            ctx=ctx)
         o = o.transpose(0, 2, 1, 3).reshape(b, s, -1)
         out = jnp.einsum("bsh,hd->bsd", o, _wo_weight(params, x.dtype))
     if par.rs_outputs:
@@ -298,6 +299,7 @@ def attn_decode(params, x_t, cfg: ModelConfig, kv_cache, pos, ctx,
             # dequantizes per page, in VMEM, only for live table entries)
             o = paged_decode_attention(q, k_pages, v_pages, block_tables,
                                        pos, k_scale=k_sc, v_scale=v_sc,
+                                       scale=cfg.attention_multiplier,
                                        ctx=ctx)
         o = o.transpose(0, 2, 1, 3).reshape(b, 1, -1)
         out = jnp.einsum("bsh,hd->bsd", o, _wo_weight(params, x_t.dtype))
@@ -324,7 +326,8 @@ def attn_decode(params, x_t, cfg: ModelConfig, kv_cache, pos, ctx,
                 policy=policy.kernel() if policy is not None else None,
                 w_scale=params.get("wo_scale"))
             return out, new_cache
-        o = decode_attention(q, k_cache, v_cache, pos, ctx=ctx)
+        o = decode_attention(q, k_cache, v_cache, pos,
+                             scale=cfg.attention_multiplier, ctx=ctx)
     o = o.transpose(0, 2, 1, 3).reshape(b, 1, -1)
     out = jnp.einsum("bsh,hd->bsd", o, _wo_weight(params, x_t.dtype))
     return out, new_cache

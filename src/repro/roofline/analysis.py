@@ -189,7 +189,7 @@ def analytic_hbm_bytes(cfg: ModelConfig, shape: ShapeConfig,
     else:  # hybrid: mamba backbone + amortized shared attn block
         d_in = cfg.ssm.expand * d
         act_elems = 3 * d + 6 * d_in + (5 * d + 4 * h * hd + 2 * dff) \
-            / max(cfg.hybrid.attn_every, 1)
+            * cfg.attention_layers / L
     acts = act_elems * db * tokens * L * passes / chips
 
     moe_bytes = 0.0
@@ -216,7 +216,7 @@ def analytic_hbm_bytes(cfg: ModelConfig, shape: ShapeConfig,
         else:  # hybrid
             s_ = cfg.ssm
             nh = s_.expand * d // s_.head_dim
-            n_apps = L // cfg.hybrid.attn_every
+            n_apps = cfg.attention_layers
             cache_bytes = (L * B * nh * s_.state_dim * s_.head_dim * 4 * 2
                            + n_apps * B * hkv * S * hd * db * 2) / chips
         if shape.kind == "decode":

@@ -36,7 +36,12 @@ compound directly into tail latency.
 
 Per-slot prefill uses a single-sequence prefill jit and writes the result
 into the batch cache at the slot index (dynamic_update_slice), so a new
-request joins without recompiling or disturbing other slots.  Admission is
+request joins without recompiling or disturbing other slots.  A model with
+recurrent state (``model.recurrent_state`` names its cache entries, e.g.
+the Mamba layers' SSM and conv state) has that state written whole from
+the prefill's final state into the slot, so a reused slot never starts
+from its last occupant's state; its prompt pages are never shared, since
+a page cannot resume a recurrence.  Admission is
 batched: all admissible pending requests are prefilled, then their first
 tokens cross to the host in one stacked transfer.
 
@@ -53,8 +58,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.serve.spans import (ADMIT, ADMIT_FIRST_TOKENS, ADMIT_PREFILL,
-                               ADMIT_REAP, ADMIT_SCATTER, STEP, SYNC,
-                               SYNC_APPLY, SYNC_FETCH, span)
+                               ADMIT_REAP, ADMIT_SCATTER, ADMIT_STATE, STEP,
+                               SYNC, SYNC_APPLY, SYNC_FETCH, span)
+
+#: a prefill cache's KV strips, relaid out for token-major pages
+_STRIPS = ("k", "v", "k_scale", "v_scale")
 
 #: max ticks between harvest syncs once admissions have drained: bounds
 #: how much masked decode work a fully-EOS'd batch can waste
@@ -202,6 +210,10 @@ class BatchedEngine:
         self.param_layout = getattr(model, "param_layout", None)
         self.params = params
         self.cfg = cfg
+        # cache entries of per-slot recurrent state (none for a pure
+        # attention model): written whole at admission, never shared
+        self._recurrent = tuple(getattr(model, "recurrent_state", ()))
+        self._prefix_sharing = cfg.prefix_sharing and not self._recurrent
         b = cfg.batch_slots
         self._paged = cfg.paged
         if self._paged:
@@ -251,6 +263,9 @@ class BatchedEngine:
         # whole new pool (a pool-sized allocation every admission)
         self._write_pages = jax.jit(self._write_pages_impl,
                                     donate_argnums=(0,) if donate else ())
+        # admission's write of a slot's recurrent state, donated likewise
+        self._write_state = jax.jit(self._write_state_impl,
+                                    donate_argnums=(0,) if donate else ())
 
     def page_footprint_bytes(self) -> int:
         """Device bytes one KV page costs across the layer stack: K + V
@@ -265,7 +280,7 @@ class BatchedEngine:
             per_layer = 2 * hkv * ps * (hd + 4)
         else:
             per_layer = 2 * hkv * ps * hd * np.dtype(mcfg.dtype).itemsize
-        return mcfg.num_layers * per_layer
+        return mcfg.attention_layers * per_layer
 
     # ---- slot management ----
 
@@ -284,7 +299,7 @@ class BatchedEngine:
         for every prompt length."""
         logits, cache1 = self.model.prefill(params, {"tokens": tokens})
         if self._paged and self.model.token_major_pages:
-            cache1 = {name: a.swapaxes(2, 3) if a.ndim == 5 else a
+            cache1 = {name: a.swapaxes(2, 3) if name in _STRIPS else a
                       for name, a in cache1.items()}
         return logits, cache1
 
@@ -337,6 +352,12 @@ class BatchedEngine:
                           shared_pages=n_shared):
                     self._write_slot_paged(slot, cache1, len(req.prompt),
                                            page_ids, n_shared)
+                if self._recurrent:
+                    with span(ADMIT_STATE, rid=req.rid):
+                        self.cache.update(self._write_state(
+                            {n: self.cache[n] for n in self._recurrent},
+                            {n: cache1[n] for n in self._recurrent},
+                            jnp.int32(slot)))
             else:
                 with span(ADMIT_SCATTER, rid=req.rid):
                     self._write_slot(slot, cache1, len(req.prompt))
@@ -431,7 +452,7 @@ class BatchedEngine:
         reserve = self._page_reserve(req)
         shared: List[int] = []
         hashes = (PagePool.prefix_hashes(req.prompt, ps)[:reserve - 1]
-                  if self.cfg.prefix_sharing else [])
+                  if self._prefix_sharing else [])
         for h in hashes:
             pid = self.pool.lookup_prefix(h)
             if pid is None:
@@ -507,6 +528,13 @@ class BatchedEngine:
                                             mode="drop")
         return out
 
+    def _write_state_impl(self, states, new, slot):
+        """Write a batch-1 prefill's final recurrent state (``[L,1,...]``
+        each) over slot ``slot`` of the ``[L,B,...]`` state arrays."""
+        return {n: jax.lax.dynamic_update_slice_in_dim(
+            a, new[n].astype(a.dtype), slot, axis=1)
+            for n, a in states.items()}
+
     # ---- ticking ----
 
     def _tick_impl(self, params, tokens, live, remaining, cache):
@@ -524,15 +552,18 @@ class BatchedEngine:
         if not self._paged:
             return nxt, live, remaining, cache
         # per-tick observability, computed inside the one program: live
-        # slot count + pages actually reached by live frontiers.  A tiny
-        # device vector appended to history — harvested by sync(), so the
-        # tick stays transfer-free.
+        # slot count, pages actually reached by live frontiers, and the
+        # live slots' positions summed (each slot's KV length, its new
+        # token included).  A tiny device vector appended to history —
+        # harvested by sync(), so the tick stays transfer-free.
         # ceil, not floor+1: a frontier sitting exactly on a page boundary
         # (pos == k·ps) has written k pages, not k+1 (ISSUE 9 off-by-one)
         frontier = jnp.where(live, -(-cache["pos"] // self.cfg.page_size),
                              0)
         stats = jnp.stack([jnp.sum(live.astype(jnp.int32)),
-                           jnp.sum(frontier).astype(jnp.int32)])
+                           jnp.sum(frontier).astype(jnp.int32),
+                           jnp.sum(jnp.where(live, cache["pos"], 0)
+                                   ).astype(jnp.int32)])
         return nxt, live, remaining, cache, stats
 
     def step(self) -> None:
@@ -574,7 +605,7 @@ class BatchedEngine:
             pending["hist"] = jnp.stack(self._history)   # [T, B]
             self._history = []
         if self._stats_history:
-            pending["stats"] = jnp.stack(self._stats_history)   # [T, 2]
+            pending["stats"] = jnp.stack(self._stats_history)   # [T, 3]
             pending["stats_base"] = self.tick_count \
                 - len(self._stats_history)
             self._stats_history = []
@@ -606,6 +637,7 @@ class BatchedEngine:
                         "tick": base + i,
                         "live_slots": int(rows[i, 0]),
                         "frontier_pages": int(rows[i, 1]),
+                        "live_positions": int(rows[i, 2]),
                         "pool_occupied_pages": self.pool.occupied_pages,
                         "pool_utilization":
                             self.pool.occupied_pages / max(self.num_pages, 1),

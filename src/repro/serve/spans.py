@@ -14,6 +14,8 @@ Nesting, as :class:`~repro.serve.engine.BatchedEngine` opens them::
         serve.admit.prefill  x n    prompt upload + prefill dispatch (rid)
         serve.admit.scatter  x n    the prefill cache into the slot (rid,
                                     fresh_pages, shared_pages)
+        serve.admit.state    x n    the prefill's final recurrent state
+                                    into the slot (rid; models with one)
         serve.admit.first_tokens    the one fetch + tick-state updates
     serve.step                      the tick dispatch
     serve.sync
@@ -28,6 +30,7 @@ ADMIT = "serve.admit"
 ADMIT_REAP = "serve.admit.reap"
 ADMIT_PREFILL = "serve.admit.prefill"
 ADMIT_SCATTER = "serve.admit.scatter"
+ADMIT_STATE = "serve.admit.state"
 ADMIT_FIRST_TOKENS = "serve.admit.first_tokens"
 STEP = "serve.step"
 SYNC = "serve.sync"

@@ -400,8 +400,8 @@ class TestPagedEngine:
 
     def test_tick_stats_harvested_in_sync(self, model_and_params):
         """ISSUE 6 satellite: per-tick stats ride the device history and
-        drain in sync() — live slots, frontier pages, pool utilization,
-        shared-prefix hits."""
+        drain in sync() — live slots, frontier pages, live positions, pool
+        utilization, shared-prefix hits."""
         model, params, cfg = model_and_params
         eng = BatchedEngine(model, params, ServeConfig(
             batch_slots=2, max_seq_len=CACHE_LEN, eos_id=-1,
@@ -410,9 +410,12 @@ class TestPagedEngine:
         assert len(eng.tick_stats) == eng.tick_count
         first = eng.tick_stats[0]
         assert set(first) == {"tick", "live_slots", "frontier_pages",
-                              "pool_occupied_pages", "pool_utilization",
-                              "shared_prefix_hits"}
+                              "live_positions", "pool_occupied_pages",
+                              "pool_utilization", "shared_prefix_hits"}
         assert first["live_slots"] == 2
+        # both slots live: their KV lengths, the new token included
+        assert first["live_positions"] == sum(
+            len(r.prompt) + 1 for r in self._reqs(cfg)[:2])
         assert 0 < first["frontier_pages"] <= eng.num_pages
         assert 0.0 < first["pool_utilization"] <= 1.0
 

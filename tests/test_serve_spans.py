@@ -141,3 +141,49 @@ def test_tick_hlo_text_is_the_compiled_tick(model_params):
     assert text.startswith("HloModule jit__tick_impl")
     assert 'op_name="jit(_tick_impl)' in text
     assert eng.trace_count == 1                  # served from the trace
+
+
+@pytest.fixture(scope="module")
+def hybrid_params():
+    from repro import configs
+    model = build_model(configs.get_reduced("granite-4.0-h-micro"),
+                        ParallelConfig(remat="none"))
+    return model, model.init_params(jax.random.PRNGKey(0))
+
+
+def test_hybrid_admission_writes_state_per_request(hybrid_params, recorded):
+    """A model with recurrent state: its slot's state is written from the
+    prefill in a span of its own, after the page write; nothing shared."""
+    eng = engine(hybrid_params)
+    eng.admit([Request(rid=3, prompt=prompt(16), max_new_tokens=2),
+               Request(rid=5, prompt=prompt(16), max_new_tokens=2)])
+    A = (spans.ADMIT,)
+    assert [(p, n, m) for p, n, m in recorded if p == A] == [
+        (A, spans.ADMIT_REAP, {}),
+        (A, spans.ADMIT_PREFILL, {"rid": 3}),
+        (A, spans.ADMIT_SCATTER, {"rid": 3, "fresh_pages": 3,
+                                  "shared_pages": 0}),
+        (A, spans.ADMIT_STATE, {"rid": 3}),
+        (A, spans.ADMIT_PREFILL, {"rid": 5}),
+        (A, spans.ADMIT_SCATTER, {"rid": 5, "fresh_pages": 3,
+                                  "shared_pages": 0}),
+        (A, spans.ADMIT_STATE, {"rid": 5}),
+        (A, spans.ADMIT_FIRST_TOKENS, {}),
+    ]
+
+
+def test_hybrid_tick_scopes_and_live_positions(hybrid_params):
+    """The hybrid tick names the Mamba mixers' work ``ssm`` and their
+    state's reads and writes ``ssm_state``, beside ``attention`` and
+    ``mlp``; its stats count the live slots' positions."""
+    eng = engine(hybrid_params)
+    eng.admit([Request(rid=0, prompt=prompt(9), max_new_tokens=4),
+               Request(rid=1, prompt=prompt(5, 30), max_new_tokens=4)])
+    eng.step()
+    eng.sync()
+    text = eng.tick_hlo_text()
+    for scope in ("ssm", "ssm_state", "attention", "kv_read", "mlp",
+                  "head"):
+        assert f"/{scope}/" in text, scope
+    assert eng.tick_stats[0]["live_slots"] == 2
+    assert eng.tick_stats[0]["live_positions"] == (9 + 1) + (5 + 1)

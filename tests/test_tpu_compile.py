@@ -10,7 +10,9 @@ hands it (d_model 4096, 32/8 heads, head_dim 128, d_ff 14336, vocab
 49152, bf16; 2048-token prefill rows, 16 decode slots, 128-token pages),
 together with the native rows, and so are a 2-layer full-width prefill
 and paged decode step.  The default policy's paged tick and page write
-are compiled at a served pool's size and searched for pool-sized copies.
+are compiled at a served pool's size and searched for pool-sized copies,
+and so is one period of granite-4.0-h-micro's hybrid prefill and tick,
+whose tick is searched for copies of its SSM state.
 
 The topology is described inside a module fixture, never at import: the
 TPU library may be loaded by one process at a time, and pytest-xdist
@@ -297,3 +299,75 @@ def test_pallas_paged_tick_copies_no_more_pools(chip, int8):
     found = pool_copies(text, pool_shapes(cache))
     assert len(found) <= PALLAS_POOL_COPIES["int8" if int8 else "bf16"], \
         found
+
+
+
+#: granite-4.0-h-micro at published widths, one period of its pattern
+#: (layers 0-9: Mamba-2 x5, attention, Mamba-2 x4), over a served pool
+HYBRID_SLOTS, HYBRID_PAGES, HYBRID_MAXP = 32, 240, 24
+
+
+def top_level_copies(text, shape):
+    """Instructions outside fused computations that copy, transpose or
+    slice out an array the size of ``shape`` or of its ``shape[1:]``
+    (one layer's): a fusion's own reads and its in-place
+    dynamic-update-slice are the traffic the state needs."""
+    sizes = {math.prod(shape), math.prod(shape[1:])}
+    found, fused = [], False
+    for line in text.splitlines():
+        if line and not line.startswith(" "):
+            fused = line.startswith("%fused")
+            continue
+        m = _INSTR.match(line)
+        if fused or not m:
+            continue
+        name, dims, op = m.group(1), m.group(2), m.group(3)
+        if not any(w in (name if op == "fusion" else op).replace("_", "-")
+                   for w in _COPYING):
+            continue
+        if math.prod(int(d) for d in dims.split(",") if d) in sizes:
+            found.append(name)
+    return found
+
+
+@pytest.fixture(scope="module")
+def hybrid(chip):
+    """(model, params, tokens, paged cache) of one period on the chip."""
+    from repro.configs.granite_4p0_h_micro import CONFIG as MICRO
+    cfg = dataclasses.replace(MICRO, num_layers=10,
+                              layer_types=MICRO.layer_types[:10])
+    model = build_model(cfg, ParallelConfig(remat="none"))
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+            tree)
+    params = on_chip(jax.eval_shape(model.init_params,
+                                    jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(lambda: model.init_paged_cache(
+        HYBRID_SLOTS, HYBRID_PAGES, PAGE, HYBRID_MAXP)))
+    return model, params, shape(chip, HYBRID_SLOTS, dtype=jnp.int32), cache
+
+
+def test_hybrid_prefill_compiles(chip, hybrid):
+    """The chunked SSD scan of a 2048-token prompt, 9 Mamba layers and one
+    attention layer at published widths."""
+    model, params, _, _ = hybrid
+    compiled = jax.jit(model.prefill).lower(
+        params, {"tokens": shape(chip, 1, PREFILL, dtype=jnp.int32)}
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
+
+
+def test_hybrid_tick_carries_state_in_place(chip, hybrid):
+    """The hybrid tick, donated, carries the SSM state whole: each Mamba
+    layer reads its slice inside the fusion that writes it back in place,
+    and nothing the size of the state or of one layer's state is copied,
+    sliced out or relaid out beside that.  The KV pools of the attention
+    layer move no more than the dense model's do."""
+    model, params, tokens, cache = hybrid
+    assert model.kv_layers == 1 and model.ssm_layers == 9
+    text = compiled_tick(model, params, tokens, cache)
+    assert "dynamic-update-slice" in text and "scatter" in text
+    assert top_level_copies(text, cache["ssm_state"].shape) == []
+    assert pool_copies(text, pool_shapes(cache)) == []
