@@ -24,6 +24,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import paged_decode
 from repro.parallel.sharding import ShardCtx, shard
 
 NEG_INF = -1e30
@@ -239,6 +240,15 @@ def update_paged_cache(pages, new, block_tables, pos, *,
     return pages.at[page_of, :, offset].set(rows, mode="drop")
 
 
+def pages_read(block_tables, pos, page_size: int, num_pages: int):
+    """[B] pages the paged decode kernel reads for each slot at frontiers
+    ``pos``: ``min(pos // page_size + 1, max_pages)`` (a finished slot's
+    ``pos`` runs on past its table), 0 where the slot's first entry is
+    the sentinel ``num_pages``."""
+    n = jnp.minimum(pos // page_size + 1, block_tables.shape[1])
+    return jnp.where(block_tables[:, 0] < num_pages, n, 0).astype(jnp.int32)
+
+
 def gather_paged_kv(pages, block_tables, head_dim: Optional[int] = None):
     """[P,page_size,Hkv,D] + [B,max_pages] -> a [B,Hkv,S,D] logical strip
     (``[P,page_size,Hkv·D]`` pools, heads flattened into their rows, give
@@ -246,8 +256,8 @@ def gather_paged_kv(pages, block_tables, head_dim: Optional[int] = None):
 
     Sentinel/dead entries clamp to the last real page; whatever they read
     sits past every consumer's ``pos`` frontier and is masked.  This is
-    the library-row materialization — the Pallas decode kernel gathers the
-    same pages through its index map without ever building the strip.
+    the library-row materialization — the Pallas decode kernels read the
+    same pages in place without ever building the strip.
     """
     tbl = jnp.minimum(block_tables, pages.shape[0] - 1)
     strip = pages[tbl]                     # [B, max_pages, ps, Hkv, D]
@@ -268,17 +278,28 @@ def gather_paged_scales(scale_pages, block_tables, num_kv_heads: int):
 def paged_decode_attention(q, k_pages, v_pages, block_tables, pos, *,
                            k_scale=None, v_scale=None,
                            scale: Optional[float] = None,
-                           ctx: Optional[ShardCtx] = None):
-    """Single-token attention against a paged cache (jnp reference).
+                           ctx: Optional[ShardCtx] = None,
+                           interpret: Optional[bool] = None):
+    """Single-token attention against a paged cache.
 
     q: [B,H,1,D]; pages: [P,page_size,Hkv,D] (or ``[P,page_size,Hkv·D]``);
-    block_tables: [B,max_pages]; pos: [B].  Numerically identical to
+    block_tables: [B,max_pages]; pos: [B].  Float pools on a TPU backend
+    are read in place, live pages only, by the Pallas kernel
+    (``kernels/paged_decode.py``; ``interpret`` as the policy sets it).
+    Elsewhere this is the jnp reference, numerically identical to
     :func:`decode_attention` over the gathered strip — the masked-softmax
     math never sees page boundaries.
     Int8 pages come with their ``[P,rows,lanes]`` scale pools: the
     strips are gathered first and dequantized after, so a pool is never
     dequantized whole.
     """
+    from repro.kernels import ops as kernel_ops
+    if (k_scale is None and jnp.issubdtype(k_pages.dtype, jnp.floating)
+            and kernel_ops._backend_probe() == "tpu"):
+        return paged_decode.paged_decode_attention(
+            q, k_pages, v_pages, block_tables, pos,
+            scale=q.shape[-1] ** -0.5 if scale is None else scale,
+            interpret=bool(interpret))
     k_cache = gather_paged_kv(k_pages, block_tables, q.shape[-1])
     v_cache = gather_paged_kv(v_pages, block_tables, q.shape[-1])
     if k_scale is not None:

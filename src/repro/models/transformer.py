@@ -295,12 +295,13 @@ def attn_decode(params, x_t, cfg: ModelConfig, kv_cache, pos, ctx,
                     v_scale=v_sc)
             return out, new_cache
         with jax.named_scope("kv_read"):
+            # float pools on a TPU are read in place, live pages only;
             # int8 strips dequantize after the gather (the fused kernel
             # dequantizes per page, in VMEM, only for live table entries)
-            o = paged_decode_attention(q, k_pages, v_pages, block_tables,
-                                       pos, k_scale=k_sc, v_scale=v_sc,
-                                       scale=cfg.attention_multiplier,
-                                       ctx=ctx)
+            o = paged_decode_attention(
+                q, k_pages, v_pages, block_tables, pos, k_scale=k_sc,
+                v_scale=v_sc, scale=cfg.attention_multiplier, ctx=ctx,
+                interpret=policy.interpret if policy is not None else None)
         o = o.transpose(0, 2, 1, 3).reshape(b, 1, -1)
         out = jnp.einsum("bsh,hd->bsd", o, _wo_weight(params, x_t.dtype))
         return out, new_cache

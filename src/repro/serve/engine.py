@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.models.attention import pages_read
 from repro.serve.spans import (ADMIT, ADMIT_FIRST_TOKENS, ADMIT_PREFILL,
                                ADMIT_REAP, ADMIT_SCATTER, ADMIT_STATE, STEP,
                                SYNC, SYNC_APPLY, SYNC_FETCH, span)
@@ -544,6 +545,9 @@ class BatchedEngine:
         slots keep their token frozen (the cache still advances, into
         masked positions — the fixed-shape batching contract)."""
         self.trace_count += 1            # python side effect: traces only
+        if self._paged:
+            read = pages_read(cache["block_tables"], cache["pos"],
+                              self.cfg.page_size, self.num_pages)
         logits, cache = self.model.decode_step(params, tokens, cache)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         nxt = jnp.where(live, nxt, tokens)
@@ -552,10 +556,12 @@ class BatchedEngine:
         if not self._paged:
             return nxt, live, remaining, cache
         # per-tick observability, computed inside the one program: live
-        # slot count, pages actually reached by live frontiers, and the
-        # live slots' positions summed (each slot's KV length, its new
-        # token included).  A tiny device vector appended to history —
-        # harvested by sync(), so the tick stays transfer-free.
+        # slot count, pages actually reached by live frontiers, the live
+        # slots' positions summed (each slot's KV length, its new token
+        # included), and the pages one layer's attention reads across
+        # slots (live or finished but not yet reaped).  A tiny device
+        # vector appended to history — harvested by sync(), so the tick
+        # stays transfer-free.
         # ceil, not floor+1: a frontier sitting exactly on a page boundary
         # (pos == k·ps) has written k pages, not k+1 (ISSUE 9 off-by-one)
         frontier = jnp.where(live, -(-cache["pos"] // self.cfg.page_size),
@@ -563,7 +569,8 @@ class BatchedEngine:
         stats = jnp.stack([jnp.sum(live.astype(jnp.int32)),
                            jnp.sum(frontier).astype(jnp.int32),
                            jnp.sum(jnp.where(live, cache["pos"], 0)
-                                   ).astype(jnp.int32)])
+                                   ).astype(jnp.int32),
+                           jnp.sum(read)])
         return nxt, live, remaining, cache, stats
 
     def step(self) -> None:
@@ -605,7 +612,7 @@ class BatchedEngine:
             pending["hist"] = jnp.stack(self._history)   # [T, B]
             self._history = []
         if self._stats_history:
-            pending["stats"] = jnp.stack(self._stats_history)   # [T, 3]
+            pending["stats"] = jnp.stack(self._stats_history)   # [T, 4]
             pending["stats_base"] = self.tick_count \
                 - len(self._stats_history)
             self._stats_history = []
@@ -638,6 +645,7 @@ class BatchedEngine:
                         "live_slots": int(rows[i, 0]),
                         "frontier_pages": int(rows[i, 1]),
                         "live_positions": int(rows[i, 2]),
+                        "kv_pages_read": int(rows[i, 3]),
                         "pool_occupied_pages": self.pool.occupied_pages,
                         "pool_utilization":
                             self.pool.occupied_pages / max(self.num_pages, 1),

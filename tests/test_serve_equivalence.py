@@ -400,8 +400,8 @@ class TestPagedEngine:
 
     def test_tick_stats_harvested_in_sync(self, model_and_params):
         """ISSUE 6 satellite: per-tick stats ride the device history and
-        drain in sync() — live slots, frontier pages, live positions, pool
-        utilization, shared-prefix hits."""
+        drain in sync() — live slots, frontier pages, live positions,
+        pages read, pool utilization, shared-prefix hits."""
         model, params, cfg = model_and_params
         eng = BatchedEngine(model, params, ServeConfig(
             batch_slots=2, max_seq_len=CACHE_LEN, eos_id=-1,
@@ -410,14 +410,82 @@ class TestPagedEngine:
         assert len(eng.tick_stats) == eng.tick_count
         first = eng.tick_stats[0]
         assert set(first) == {"tick", "live_slots", "frontier_pages",
-                              "live_positions", "pool_occupied_pages",
-                              "pool_utilization", "shared_prefix_hits"}
+                              "live_positions", "kv_pages_read",
+                              "pool_occupied_pages", "pool_utilization",
+                              "shared_prefix_hits"}
         assert first["live_slots"] == 2
         # both slots live: their KV lengths, the new token included
         assert first["live_positions"] == sum(
             len(r.prompt) + 1 for r in self._reqs(cfg)[:2])
         assert 0 < first["frontier_pages"] <= eng.num_pages
         assert 0.0 < first["pool_utilization"] <= 1.0
+
+    def test_paged_kernel_tick_matches_gather_tick(self, model_and_params,
+                                                   monkeypatch):
+        """The tick as a TPU runs it: the backend probe answers "tpu", so
+        the float token-major pools are read in place by the paged decode
+        kernel (here in interpret mode).  Five requests on two slots, so
+        slots are reaped and reused mid-run: the same greedy streams as
+        the gather path, from one tick program."""
+        model, params, cfg = model_and_params
+        want, _ = self._run(model, params, self._reqs(cfg),
+                            page_size=self.PAGE)
+        from repro.kernels import ops, paged_decode
+        calls = []
+        kernel = paged_decode.paged_decode_attention
+
+        def spy(*args, **kw):
+            calls.append(kw["interpret"])
+            return kernel(*args, **kw)
+        monkeypatch.setattr(ops, "_backend_probe", lambda: "tpu")
+        monkeypatch.setattr(paged_decode, "paged_decode_attention", spy)
+        on_tpu = model.with_policy(dataclasses.replace(model.policy,
+                                                       interpret=True))
+        eng = BatchedEngine(on_tpu, params, ServeConfig(
+            batch_slots=2, max_seq_len=CACHE_LEN, eos_id=-1,
+            page_size=self.PAGE))
+        reqs = self._reqs(cfg)
+        got = {r.rid: r.generated for r in eng.run(reqs)}
+        assert calls == [True]               # traced once, in the scan
+        assert len(got) == 5 and got == want
+        assert {r.slot for r in reqs} == {0, 1}      # slots reused
+        assert eng.trace_count == 1
+
+    def test_kv_pages_read_counts_from_pos_and_tables(self,
+                                                      model_and_params):
+        """``kv_pages_read`` is what the host computes from each tick's
+        ``pos`` and tables: a slot with a real first entry reads
+        ``min(pos // page + 1, max_pages)`` pages, a reaped one none.
+        Finished slots left unreaped run past their tables to the cap,
+        so the count reaches but never passes B·max_pages; reaped, they
+        read nothing."""
+        model, params, cfg = model_and_params
+        eng = BatchedEngine(model, params, ServeConfig(
+            batch_slots=2, max_seq_len=CACHE_LEN, eos_id=-1,
+            page_size=self.PAGE))
+        want = []
+
+        def tick():
+            tbl = np.asarray(eng.cache["block_tables"])
+            pos = np.asarray(eng.cache["pos"])
+            want.append(sum(min(p // self.PAGE + 1, tbl.shape[1])
+                            for t, p in zip(tbl, pos)
+                            if t[0] < eng.num_pages))
+            eng.step()
+        pending = self._reqs(cfg)
+        while pending:
+            del pending[:eng.admit(pending)]
+            tick()
+        for _ in range(CACHE_LEN + 8):
+            tick()
+        assert eng.admit([]) == 0            # syncs, then reaps both
+        tick()
+        eng.sync()
+        got = [r["kv_pages_read"] for r in eng.tick_stats]
+        cap = 2 * eng._max_pages
+        assert got == want
+        assert all(0 <= g <= cap for g in got)
+        assert got[-2:] == [cap, 0]
 
     @pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
     def test_layer_offset_keeps_pages_apart(self, int8):

@@ -10,9 +10,10 @@ hands it (d_model 4096, 32/8 heads, head_dim 128, d_ff 14336, vocab
 49152, bf16; 2048-token prefill rows, 16 decode slots, 128-token pages),
 together with the native rows, and so are a 2-layer full-width prefill
 and paged decode step.  The default policy's paged tick and page write
-are compiled at a served pool's size and searched for pool-sized copies,
-and so is one period of granite-4.0-h-micro's hybrid prefill and tick,
-whose tick is searched for copies of its SSM state.
+are compiled at a served pool's size and searched for pool-sized copies
+and for gathers of capacity strips, and so is one period of
+granite-4.0-h-micro's hybrid prefill and tick, whose tick is searched
+for copies of its SSM state.
 
 The topology is described inside a module fixture, never at import: the
 TPU library may be loaded by one process at a time, and pytest-xdist
@@ -58,11 +59,16 @@ def topo():
 
 @pytest.fixture(scope="module")
 def chip(topo):
-    """One described v5e chip; the persistent cache off while compiling."""
+    """One described v5e chip; the persistent cache off while compiling,
+    and the backend probe answering "tpu", as it does on the chip, so
+    the model picks the kernels it picks there."""
     was = jax.config.jax_enable_compilation_cache
+    probe = ops._backend_probe
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
+    ops._backend_probe = lambda: "tpu"
     yield SingleDeviceSharding(topo.devices[0])
+    ops._backend_probe = probe
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
 
@@ -270,6 +276,32 @@ def test_default_paged_tick_copies_no_pool(chip, int8):
     assert pool_copies(text, pool_shapes(cache)) == []
 
 
+def strip_gathers(text, slots, max_pages):
+    """Names of the gathers in compiled HLO ``text`` whose result is a
+    capacity strip, ``[slots, max_pages, page_size, ...]``: every table
+    entry of every slot, live or not."""
+    lead = f"{slots},{max_pages},{PAGE}"
+    return [m.group(1) for m in map(_INSTR.match, text.splitlines())
+            if m and m.group(3) == "gather"
+            and m.group(2).startswith(lead)]
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_default_paged_tick_reads_pages_in_place(chip, int8):
+    """At granite-8b widths over a served pool, the default tick reads
+    bf16 pages in place through the paged decode kernel and gathers no
+    capacity strip; int8 pools keep the gather-then-dequantize path."""
+    model, params, tokens, cache = paged_model(chip, False, int8)
+    text = compiled_tick(model, params, tokens, cache)
+    found = strip_gathers(text, TICK_SLOTS, TICK_MAXP)
+    if int8:
+        assert "uisa_paged_decode_attention" not in text
+        assert found
+    else:
+        assert "uisa_paged_decode_attention" in text
+        assert found == []
+
+
 @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
 def test_default_page_write_copies_no_pool(chip, int8):
     """Admission's page write, pools donated, writes the prompt strip's
@@ -371,3 +403,13 @@ def test_hybrid_tick_carries_state_in_place(chip, hybrid):
     assert "dynamic-update-slice" in text and "scatter" in text
     assert top_level_copies(text, cache["ssm_state"].shape) == []
     assert pool_copies(text, pool_shapes(cache)) == []
+
+
+def test_hybrid_tick_reads_pages_in_place(chip, hybrid):
+    """The hybrid's attention layer reads its pages, heads flattened into
+    512-lane rows at a head of 64, through the same kernel, and gathers
+    no capacity strip."""
+    model, params, tokens, cache = hybrid
+    text = compiled_tick(model, params, tokens, cache)
+    assert "uisa_paged_decode_attention" in text
+    assert strip_gathers(text, HYBRID_SLOTS, HYBRID_MAXP) == []
